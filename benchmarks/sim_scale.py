@@ -137,6 +137,10 @@ def dry_1024(mesh: int, n: int = 1024, reps: int = 2):
 def main(quick: bool = True, *, write_bench: bool = False):
     mesh_n = len(jax.devices())
     if mesh_n == 1:
+        if jax.default_backend() != "cpu":
+            raise SystemExit(
+                "[sim_scale] FAIL: the mesh comparison needs more than "
+                f"one {jax.default_backend()} device; this host has 1")
         print("[sim_scale] WARNING: only 1 jax device — set XLA_FLAGS="
               "--xla_force_host_platform_device_count=8 before running "
               "for a real mesh comparison")

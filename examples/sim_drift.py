@@ -1,6 +1,6 @@
 """Feature-drift end-to-end: domain shift over time with budgeted,
-drift-aware divergence re-estimation — run single-host (LocalPool),
-then replayed on an emulated 2-shard device mesh and compared
+drift-aware divergence re-estimation — run on the single-host pool
+(LocalPool), then replayed on a sharded device pool and compared
 field-for-field.
 
 8 devices under the `feature-drift` scenario: half the network's
@@ -12,18 +12,25 @@ re-measures only a budgeted stalest-first subset of the dirty pairs
 
     PYTHONPATH=src python examples/sim_drift.py
 
-The mesh replay forces 2 emulated host-platform devices, which must
-happen before the first jax import — hence the subprocess.
+Everything runs in this one process, which keeps the accelerator to
+itself.  The replay shards the pool over 2 devices: on the CPU
+(``JAX_PLATFORMS=cpu``) two host-platform devices are emulated, which
+must be requested before JAX is imported; on an accelerator the mesh
+takes as many of the 2 as the host has.
 """
 import json
 import os
-import subprocess
 import sys
 
-import numpy as np
+if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
+                               + os.environ.get("XLA_FLAGS", ""))
 
-from repro.sim import SimConfig, SimulationEngine
-from repro.sim.metrics import read_jsonl, strip_nondeterministic
+import jax  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.sim import SimConfig, SimulationEngine  # noqa: E402
+from repro.sim.metrics import strip_nondeterministic  # noqa: E402
 
 CFG = dict(scenario="feature-drift", devices=8, rounds=4, seed=0,
            samples_per_device=40, train_iters=8, div_tau=1, div_T=6,
@@ -31,7 +38,7 @@ CFG = dict(scenario="feature-drift", devices=8, rounds=4, seed=0,
            feature_drift_p=0.6, feature_drift_step=0.3,
            resolve_threshold=0.05, div_budget=6)
 LOCAL_LOG = "results/sim/example_drift.jsonl"
-MESH_LOG = "results/sim/example_drift_mesh2.jsonl"
+MESH_LOG = "results/sim/example_drift_mesh.jsonl"
 
 # ---- single-host run --------------------------------------------------
 rows = SimulationEngine(SimConfig(log_path=LOCAL_LOG, verbose=True,
@@ -48,26 +55,16 @@ print("per-round re-estimated:   ", [r["n_reestimated"] for r in rows],
 print("target accuracy trajectory:",
       np.round([r["mean_target_acc"] for r in rows], 3).tolist())
 
-# ---- emulated 2-shard mesh replay ------------------------------------
-print("\nreplaying on an emulated 2-shard device mesh ...")
-env = dict(os.environ)
-env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=2 "
-                    + env.get("XLA_FLAGS", ""))
-src = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "src")
-env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                           if env.get("PYTHONPATH") else "")
-child = f"""
-from repro.sim import SimConfig, SimulationEngine
-SimulationEngine(SimConfig(mesh=2, log_path={MESH_LOG!r},
-                           **{CFG!r})).run()
-"""
-subprocess.run([sys.executable, "-c", child], env=env, check=True)
+# ---- sharded-pool replay ----------------------------------------------
+shards = min(2, len(jax.devices()))
+print(f"\nreplaying on a {shards}-shard device mesh "
+      f"({jax.devices()[0].platform}) ...")
+mesh_rows = SimulationEngine(SimConfig(mesh=shards, log_path=MESH_LOG,
+                                       **CFG)).run()
 
-local = strip_nondeterministic(read_jsonl(LOCAL_LOG))
-mesh = strip_nondeterministic(read_jsonl(MESH_LOG))
-match = json.dumps(local, default=float) == json.dumps(mesh, default=float)
-print(f"mesh-of-2 parity vs single host: "
+match = json.dumps(strip_nondeterministic(rows), default=float) == \
+    json.dumps(strip_nondeterministic(mesh_rows), default=float)
+print(f"mesh-of-{shards} parity vs single host: "
       f"{'field-for-field OK' if match else 'MISMATCH'}")
 if not match:
     sys.exit(1)

@@ -17,14 +17,19 @@ def combine_models(params_stack, alpha, *, impl: str = "xla"):
     """params_stack: pytree with leading device axis N; alpha: (N, N)
     column-stochastic over targets (alpha[s, t]).  Returns the same pytree
     where entry t = sum_s alpha[s, t] * params[s].  Rows of sources are
-    untouched targets' mixtures; callers select which rows to keep."""
+    untouched targets' mixtures; callers select which rows to keep.
+
+    Both impls contract in full float32 (``HIGHEST``; a TPU's default
+    matmul would round the operands to bfloat16), so the XLA path and
+    the Pallas kernel compute the same mixture on every backend."""
     alpha = jnp.asarray(alpha, jnp.float32)
     if impl == "pallas":
         from repro.kernels.alpha_combine import ops as ac_ops
         return ac_ops.alpha_combine_tree(params_stack, alpha)
     return jax.tree_util.tree_map(
-        lambda p: jnp.einsum("s...,st->t...", p.astype(jnp.float32),
-                             alpha).astype(p.dtype), params_stack)
+        lambda p: jnp.einsum("s...,st->t...", p.astype(jnp.float32), alpha,
+                             precision=jax.lax.Precision.HIGHEST
+                             ).astype(p.dtype), params_stack)
 
 
 def apply_transfer(params_stack, alpha, psi):

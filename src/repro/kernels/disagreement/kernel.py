@@ -13,9 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels.compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _disagree_kernel(pi_ref, pj_ref, vm_ref, out_ref, acc_ref):
@@ -62,7 +60,7 @@ def disagreement_counts(preds, valid, *, block_n: int = 128,
         out_specs=pl.BlockSpec((bn, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, bn), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(p, p, v)

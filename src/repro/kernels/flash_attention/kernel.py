@@ -19,9 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import TPUCompilerParams
 
 NEG_INF = -2.0e9
 DEFAULT_BLOCK_Q = 128
@@ -117,7 +116,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),      # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -28,9 +28,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels.compat import TPUCompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gla_kernel(q_ref, k_ref, v_ref, lw_ref, bonus_ref, s0_ref,
@@ -109,7 +107,7 @@ def gla_chunked_bhncd(q, k, v, lw, bonus, s0, *, chunk: int, variant: str,
             jax.ShapeDtypeStruct((bh, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, lw, bonus, s0)

@@ -47,11 +47,17 @@ def make_local_mesh(model_axis: Optional[int] = None, *,
     how many are meshed (default: all of them), and ``axis_names``
     renames the two axes — the sim's sharded device pool builds its
     1-wide-model ('devices', ...) mesh through here instead of growing a
-    second local-mesh factory."""
+    second local-mesh factory.
+
+    Both axes are ``Auto``: arrays that leave a shard_map stay plain
+    device-sharded values, so host-side eager updates such as
+    ``params.at[j].set(...)`` need no output-sharding annotation (the
+    ``Explicit`` default of ``jax.make_mesh`` would reject them)."""
     devs = jax.devices()
     n = len(devs) if max_devices is None else min(max_devices, len(devs))
     m = model_axis or 1
     if n < m:
         raise RuntimeError(f"model_axis={m} needs {m} devices, found {n}")
     n = (n // m) * m                    # drop any remainder (historical)
-    return jax.make_mesh((n // m, m), axis_names, devices=devs[:n])
+    return jax.make_mesh((n // m, m), axis_names,
+                         (jax.sharding.AxisType.Auto,) * 2, devices=devs[:n])

@@ -331,6 +331,7 @@ class SimulationEngine:
         #: both can reference it unconditionally
         self.trace = TraceRecorder(cfg)
         self.pool = make_pool(self)
+        self.state.clients = self.pool.place_clients(self.state.clients)
         self.executor = get_executor(cfg.engine)(self)
         self.executor.setup()
         self.scenario.setup(self)

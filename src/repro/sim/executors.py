@@ -121,7 +121,8 @@ class Executor:
         eng.trace.begin_tick(t)
         events = eng.scenario.step(eng, t)
         if eng._restack:
-            eng.state.clients = stack_clients(eng.state.pool)
+            eng.state.clients = eng.pool.place_clients(
+                stack_clients(eng.state.pool))
             eng._restack = False
         return t0, events
 
@@ -291,7 +292,10 @@ class Executor:
             link_churn=float(churn), events=events,
             wall_time_s=time.time() - t0,
             engine=self.name, solve_age=int(solve_age),
-            resolve_reason=reason, n_drifted=int(n_drifted),
+            resolve_reason=reason, targets=[int(j) for j in tgt],
+            # the tick's link set, which _link_churn has just stored
+            links=[list(link) for link in sorted(eng._prev_links)],
+            n_drifted=int(n_drifted),
             n_dirty_pairs=int(n_dirty_pairs),
             n_reestimated=int(n_reestimated),
             n_faults=int(n_faults), n_recovered=int(n_recov),

@@ -49,6 +49,9 @@ their defaults under sync):
   resolve_reason   str?   why the gate fired: cold | membership | drift
                           | staleness (async staleness bound); null when
                           no re-solve ran
+  targets          list   active device ids with psi == 1 (sorted)
+  links            list   active links [source, target]: solved alpha
+                          above link_thresh (sorted)
 
 Feature-drift / dirty-pair fields (added with the drift-aware budgeted
 re-estimation; all 0 on ticks where nothing drifts, so pre-drift
@@ -138,6 +141,10 @@ class RoundRecord:
     max_staleness: float = -1.0
     solve_age: int = -1
     resolve_reason: Optional[str] = None
+    # the installed solve decisions: active targets (psi = 1) and active
+    # links [source, target] (alpha above link_thresh), both sorted
+    targets: List[int] = dataclasses.field(default_factory=list)
+    links: List[List[int]] = dataclasses.field(default_factory=list)
     # feature-drift / dirty-pair fields (0 when nothing drifts)
     n_drifted: int = 0
     n_dirty_pairs: int = 0

@@ -13,6 +13,7 @@ import sys
 
 import numpy as np
 
+from repro.sim.compile_cache import enable_compile_cache
 from repro.sim.engine import SimConfig, SimulationEngine
 from repro.sim.executors import EXECUTORS
 from repro.sim.scenarios import SCENARIOS
@@ -164,6 +165,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     tag = "" if args.engine == "sync" else f"-{args.engine}"
     out = args.out or os.path.join(
         "results", "sim",

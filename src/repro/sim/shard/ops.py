@@ -28,14 +28,13 @@ needs beyond its own block arrives through an explicit collective:
 Because every per-lane computation is the single-host one and lanes are
 independent, a sharded run reproduces the single-host trajectory
 bit-for-bit — the mesh changes WHERE lanes run, never what they
-compute.  (``check_rep=False``: pallas_call has no replication rule;
+compute.  (``check_vma=False``: pallas_call has no replication rule;
 every output here is genuinely device-sharded anyway.)
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.fl.client import true_accuracies
@@ -47,8 +46,8 @@ from repro.sim.training import network_step_core
 
 
 def _smap(body, mesh, in_specs, out_specs):
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def build_train_step(mesh, *, iters: int, batch: int, lr: float):

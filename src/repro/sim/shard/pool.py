@@ -36,12 +36,14 @@ from typing import TYPE_CHECKING, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.fl.divergence import (chunked_pair_lanes,
                                  pairwise_divergence_values)
 from repro.fl.divergence import update_divergences as _update_divergences
 from repro.fl.transfer import apply_transfer
 from repro.sim.faults import PoolFaultError, with_retry
+from repro.sim.shard.mesh import DEVICE_AXIS
 from repro.sim.training import (mixed_accuracies, network_step,
                                 subset_network_step)
 
@@ -180,6 +182,12 @@ class DevicePool:
         self.engine.trace.stop("eval", t0, block=out,
                                n_devices=clients.n_devices)
         return out
+
+    def place_clients(self, clients):
+        """Where the client stack lives between ticks; the engine passes
+        every freshly stacked ``StackedClients`` through here.  The base
+        keeps it on the default device."""
+        return clients
 
     # -------------------------------------------------- backend hooks
     def _train(self, params, clients, key, active, train_mask=None):
@@ -345,7 +353,7 @@ class ShardedPool(DevicePool):
         super().__init__(engine)
         from repro.sim.shard import mesh as mesh_lib, ops
         self.mesh = mesh_lib.make_pool_mesh(n_shards)
-        self.n_shards = self.mesh.shape[mesh_lib.DEVICE_AXIS]
+        self.n_shards = self.mesh.shape[DEVICE_AXIS]
         self.name = f"sharded-{self.n_shards}"
         cfg = engine.cfg
         self._train_fn = ops.build_train_step(
@@ -355,6 +363,16 @@ class ShardedPool(DevicePool):
             lr=cfg.lr)
         self._transfer_fn = ops.build_transfer(self.mesh)
         self._acc_fn = ops.build_accuracies(self.mesh)
+
+    def place_clients(self, clients):
+        """Shard the client stack over the pool mesh once, so each chip
+        holds its own block instead of every call copying the whole
+        stack out of the default device.  A pool that does not divide
+        the shard count stays put: its calls pad, then shard."""
+        if self._pad(clients.n_devices):
+            return clients
+        return jax.device_put(clients, NamedSharding(
+            self.mesh, PartitionSpec(DEVICE_AXIS)))
 
     # ------------------------------------------------------ pool padding
     def _pad(self, n: int) -> int:

@@ -244,7 +244,7 @@ def restore_run(engine: "SimulationEngine") -> int:
 
     for j in range(st.pool_size):
         st.pool[j] = _device_from(arrs, "pool", _slot(j))
-    st.clients = stack_clients(st.pool)
+    st.clients = engine.pool.place_clients(stack_clients(st.pool))
 
     sol_meta = meta["solver"]
     if sol_meta["present"]:

@@ -1,7 +1,8 @@
 """Synthetic domains + federated partitioning."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.data import (DOMAINS, NUM_CLASSES, build_network,
                         dirichlet_label_split, make_domain_dataset,

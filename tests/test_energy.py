@@ -1,7 +1,8 @@
 """Communication-energy model (eq. 14 + Sec. V determination)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.energy import EnergyModel, dbm_to_watts
 

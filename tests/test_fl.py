@@ -3,7 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.data import build_network
 from repro.fl import (apply_transfer, column_normalize, combine_models,

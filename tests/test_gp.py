@@ -1,6 +1,7 @@
 """GP machinery: the AGM monomial bound (Lemma 2) as a property test."""
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.gp import Monomial, Posynomial, pack_monomial, \
     pack_posynomial

@@ -140,13 +140,28 @@ def test_disagreement_properties():
 
 
 # ------------------------------------------------------------- alpha combine
-@pytest.mark.parametrize("s,t,p", [(4, 3, 1000), (8, 8, 5000), (2, 1, 64)])
+@pytest.mark.parametrize("s,t,p", [
+    (4, 3, 1000), (8, 8, 5000), (2, 1, 64),
+    (1024, 1024, 600),                  # S tiled: accumulates over 2 blocks
+    (128, 8192, 128),                   # T tiled: 2 target blocks
+])
 def test_alpha_combine_matches_ref(s, t, p):
     th = jnp.asarray(RNG.normal(size=(s, p)), jnp.float32)
     al = jnp.asarray(RNG.random((s, t)), jnp.float32)
     np.testing.assert_allclose(np.asarray(alpha_combine(th, al)),
                                np.asarray(alpha_combine_ref(th, al)),
                                atol=1e-4)
+
+
+@pytest.mark.parametrize("s,t", [(64, 64), (1024, 1024), (1024, 256),
+                                 (4096, 4096), (8192, 2048)])
+def test_alpha_combine_tiles_fit_vmem(s, t):
+    from repro.kernels.alpha_combine.kernel import VMEM_BUDGET, _tiles
+    bs, bt, bp = _tiles(s, t, 48_158, 2048)
+    assert 4 * (2 * bs * bt + 2 * bs * bp + 3 * bt * bp) <= VMEM_BUDGET
+    assert bp % 128 == 0
+    assert bs == s or bs % 128 == 0
+    assert bt == t or bt % 128 == 0
 
 
 def test_alpha_combine_tree_matches_einsum():

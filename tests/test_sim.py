@@ -124,9 +124,16 @@ def test_resolves_after_round_zero_are_warm():
     assert all(r["warm"] for r in later)
 
 
+def _canon(rows):
+    """NaN-aware comparable form: a tick with no targets (or no sources)
+    reports a NaN mean accuracy, and NaN != NaN breaks dict equality;
+    JSON renders every NaN alike."""
+    return json.dumps(strip_nondeterministic(rows), default=float)
+
+
 def test_engine_deterministic_per_seed():
-    a = strip_nondeterministic(_run("channel-drift", devices=6, rounds=2))
-    b = strip_nondeterministic(_run("channel-drift", devices=6, rounds=2))
+    a = _canon(_run("channel-drift", devices=6, rounds=2))
+    b = _canon(_run("channel-drift", devices=6, rounds=2))
     assert a == b
 
 
@@ -144,8 +151,7 @@ def test_metrics_jsonl_written(tmp_path):
                     log_path=out, **SMOKE)
     rows = SimulationEngine(cfg).run()
     from repro.sim.metrics import read_jsonl
-    assert strip_nondeterministic(read_jsonl(out)) \
-        == strip_nondeterministic(rows)
+    assert _canon(read_jsonl(out)) == _canon(rows)
 
 
 # --------------------------------------------------------- device clocks
@@ -205,14 +211,9 @@ def test_async_gossip_smoke():
 
 
 def test_async_deterministic_per_seed_and_seed_sensitivity():
-    # early async ticks can have zero targets -> NaN accuracies, which
-    # break dict equality; compare the serialized form instead
-    def canon(rows):
-        return json.dumps(strip_nondeterministic(rows), default=float)
-
-    a = canon(_run_async("stragglers", rounds=4))
-    b = canon(_run_async("stragglers", rounds=4))
-    c = canon(_run_async("stragglers", rounds=4, seed=1))
+    a = _canon(_run_async("stragglers", rounds=4))
+    b = _canon(_run_async("stragglers", rounds=4))
+    c = _canon(_run_async("stragglers", rounds=4, seed=1))
     assert a == b
     assert a != c
 
@@ -255,7 +256,9 @@ def test_async_64_devices_40_ticks_staleness_resolve():
 
 # ------------------------------------------------- churn-robust re-seeding
 def test_rejoining_device_reseeded_from_source_mixture():
-    cfg = SimConfig(scenario="static", devices=6, rounds=1, **SMOKE)
+    # 8 devices: the smallest smoke network whose round-0 solve picks
+    # targets (at 6 every device stays a source)
+    cfg = SimConfig(scenario="static", devices=8, rounds=1, **SMOKE)
     eng = SimulationEngine(cfg)
     eng.step(0)                                   # install a solution
     st = eng.state
@@ -296,11 +299,13 @@ def test_rejoin_keeps_stale_params_when_reseed_disabled():
 
 # --------------------------------------------------- link_thresh plumbing
 def test_link_thresh_threads_through_metrics():
-    rows = _run("static", devices=6, rounds=1, link_thresh=10.0)
+    rows = _run("static", devices=8, rounds=1, link_thresh=10.0)
     assert rows[0]["transmissions"] == 0
     assert rows[0]["link_churn"] == 0.0
-    base = _run("static", devices=6, rounds=1)
-    assert base[0]["transmissions"] > 0
+    assert rows[0]["links"] == []
+    base = _run("static", devices=8, rounds=1)
+    assert base[0]["transmissions"] == len(base[0]["links"]) > 0
+    assert {t for _, t in base[0]["links"]} <= set(base[0]["targets"])
 
 
 # --------------------------------------------- unknown-divergence prior

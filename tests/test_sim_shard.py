@@ -146,6 +146,16 @@ def test_sharded_train_matches_local_pool():
     np.testing.assert_array_equal(np.asarray(acc_sh), np.asarray(acc_ref))
 
 
+def test_sharded_pool_keeps_clients_on_its_mesh():
+    eng = _tiny_engine(mesh=1)
+    x = eng.state.clients.x
+    assert x.sharding.mesh.devices.size == eng.pool.mesh.devices.size
+    assert x.sharding.spec[0] == DEVICE_AXIS
+    local = _tiny_engine()
+    assert local.pool.place_clients(local.state.clients) \
+        is local.state.clients
+
+
 def test_sharded_pool_pads_non_dividing_pool():
     """mesh-of-1 never pads; fake a 2-shard pool boundary by checking
     the padding helpers directly (a real 2-shard mesh needs 2 devices)."""
