@@ -14,9 +14,9 @@ RESULTS_DIR = os.environ.get("REPRO_BENCH_DIR", "results/bench")
 
 def host_fingerprint() -> dict:
     """Provenance stamp for benchmark artifacts: enough to tell whether
-    two BENCH_*.json files were measured on comparable hosts (the trace
-    cost model is wall-clock data — a fit from one box must not be
-    silently compared against walls from another)."""
+    two BENCH_*.json files were measured on comparable hosts (walls
+    from one box must not be silently compared against walls from
+    another)."""
     import platform
     devs = jax.devices()
     return {

@@ -13,7 +13,7 @@ from benchmarks.common import save_rows
 
 BENCHES = ["fig4", "fig5", "fig6", "fig8", "fig9", "table2", "roofline",
            "sim_warmstart", "sim_async", "sim_scale", "sim_drift",
-           "sim_trace", "solver_scaling"]
+           "solver_scaling"]
 
 
 def _module(name: str):
@@ -30,7 +30,6 @@ def _module(name: str):
         "sim_async": "benchmarks.sim_async",
         "sim_scale": "benchmarks.sim_scale",
         "sim_drift": "benchmarks.sim_drift",
-        "sim_trace": "benchmarks.sim_trace",
         "solver_scaling": "benchmarks.solver_scaling",
     }[name]
     return importlib.import_module(mod)

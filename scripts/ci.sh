@@ -41,11 +41,6 @@ python -m repro.sim.run --engine async-gossip \
 # documented in docs/metrics-schema.md
 python scripts/check_docs.py
 
-# trace/cost-model gate: a short traced sim, the cost model fitted on
-# its own trace, and the replay prediction for the same config must
-# land within a generous 2x band of the phase-measured wall
-python -m benchmarks.sim_trace --ci
-
 # emulated-mesh smoke gate: the sharded device pool on 8 forced
 # host-platform devices (XLA_FLAGS must precede the first jax import,
 # hence fresh processes), both engines end-to-end through the CLI, then

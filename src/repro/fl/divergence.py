@@ -118,7 +118,8 @@ def pairwise_divergence_values(h0, clients: StackedClients, pair_i, pair_j,
         eps = (wi + wj) / jnp.maximum(ni + nj, 1.0)
         return jnp.clip(2.0 * (1.0 - 2.0 * eps), 0.0, 2.0)
 
-    return jax.vmap(one_pair)(pair_i, pair_j, keys)
+    with jax.named_scope("pair_scan"):
+        return jax.vmap(one_pair)(pair_i, pair_j, keys)
 
 
 def pair_keys(key, npairs: int, pair_chunk: int = 256):

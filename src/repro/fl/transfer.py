@@ -26,10 +26,11 @@ def combine_models(params_stack, alpha, *, impl: str = "xla"):
     if impl == "pallas":
         from repro.kernels.alpha_combine import ops as ac_ops
         return ac_ops.alpha_combine_tree(params_stack, alpha)
-    return jax.tree_util.tree_map(
-        lambda p: jnp.einsum("s...,st->t...", p.astype(jnp.float32), alpha,
-                             precision=jax.lax.Precision.HIGHEST
-                             ).astype(p.dtype), params_stack)
+    with jax.named_scope("transfer_combine"):
+        return jax.tree_util.tree_map(
+            lambda p: jnp.einsum("s...,st->t...", p.astype(jnp.float32),
+                                 alpha, precision=jax.lax.Precision.HIGHEST
+                                 ).astype(p.dtype), params_stack)
 
 
 def apply_transfer(params_stack, alpha, psi):

@@ -9,6 +9,10 @@ import jax.numpy as jnp
 from repro.kernels.alpha_combine.kernel import alpha_combine_flat
 from repro.nn.param import flatten_to_vector, unflatten_from_vector
 
+#: the layer name the combine's ops carry in the HLO metadata (the same
+#: as the XLA combine's in ``fl.transfer.combine_models``)
+SCOPE = "transfer_combine"
+
 
 def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
@@ -18,7 +22,8 @@ def alpha_combine(theta, alpha, *, interpret: Optional[bool] = None):
     """theta: (S, P); alpha: (S, T) -> (T, P)."""
     if interpret is None:
         interpret = _on_cpu()
-    return alpha_combine_flat(theta, alpha, interpret=interpret)
+    with jax.named_scope(SCOPE):
+        return alpha_combine_flat(theta, alpha, interpret=interpret)
 
 
 def alpha_combine_slab(theta, alpha_cols, *,
@@ -31,8 +36,10 @@ def alpha_combine_slab(theta, alpha_cols, *,
     the interconnect once regardless of how many shards consume them."""
     if interpret is None:
         interpret = _on_cpu()
-    return alpha_combine_flat(theta, jnp.asarray(alpha_cols, jnp.float32),
-                              interpret=interpret)
+    with jax.named_scope(SCOPE):
+        return alpha_combine_flat(theta,
+                                  jnp.asarray(alpha_cols, jnp.float32),
+                                  interpret=interpret)
 
 
 def alpha_combine_tree(params_stack, alpha, *,
@@ -40,9 +47,9 @@ def alpha_combine_tree(params_stack, alpha, *,
     """Pytree with leading device axis -> same pytree, mixed columns."""
     if interpret is None:
         interpret = _on_cpu()
-    s = alpha.shape[0]
     flat = jax.vmap(flatten_to_vector)(params_stack)      # (S, P)
-    mixed = alpha_combine_flat(flat, jnp.asarray(alpha, jnp.float32),
-                               interpret=interpret)       # (T, P)
+    with jax.named_scope(SCOPE):
+        mixed = alpha_combine_flat(flat, jnp.asarray(alpha, jnp.float32),
+                                   interpret=interpret)   # (T, P)
     like = jax.tree_util.tree_map(lambda a: a[0], params_stack)
     return jax.vmap(lambda v: unflatten_from_vector(v, like))(mixed)

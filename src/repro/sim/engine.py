@@ -204,19 +204,19 @@ class SimConfig:
     #: base of the exponential retry backoff, seconds (0: no sleeping)
     fault_backoff_s: float = 0.0
     # ---- trace subsystem (repro.sim.trace)
-    #: record per-phase wall-clock events (train / divergence /
-    #: transfer / solve / eval / checkpoint) into the RoundRecord
-    #: ``*_wall_s`` fields; off by default — tracing-off runs are
-    #: bit-for-bit the pre-trace engine (no PRNG use, no extra
-    #: device synchronization)
+    #: record every phase of a tick (sim/trace/events.py PHASES) as an
+    #: event and a ``sim.<phase>`` profiler span, with per-tick totals
+    #: in the RoundRecord ``*_wall_s`` fields and ``n_compiled``; off by
+    #: default — tracing-off runs are bit-for-bit the uninstrumented
+    #: engine (no PRNG use, no extra device synchronization)
     trace: bool = False
-    #: optional standalone JSONL trace file for the recorded events
-    #: (the cost-model fit input; requires ``trace=True``)
+    #: optional standalone JSONL file for the recorded events
+    #: (requires ``trace=True``)
     trace_path: Optional[str] = None
     #: floor of the power-of-two bucket widths the async subset-gather
-    #: training step compiles for (LocalPool; the autotuner's "gather
-    #: bucket size" knob).  Width choice never changes per-lane values,
-    #: only batch padding, so this is trajectory-preserving
+    #: training step compiles for (LocalPool).  Width choice never
+    #: changes per-lane values, only batch padding, so this is
+    #: trajectory-preserving
     train_gather_floor: int = 4
     log_path: Optional[str] = None
     verbose: bool = False
@@ -326,7 +326,7 @@ class SimulationEngine:
         self.faults = None
         #: how many times this run has been resumed from a checkpoint
         self._resume_count = 0
-        #: per-phase wall-clock recorder (repro.sim.trace) — a no-op
+        #: per-phase span recorder (repro.sim.trace) — a no-op
         #: unless cfg.trace; constructed before the pool/executor so
         #: both can reference it unconditionally
         self.trace = TraceRecorder(cfg)
@@ -590,13 +590,12 @@ class SimulationEngine:
             return
         from repro.checkpoint import gc_checkpoints
         from repro.sim.snapshot import save_run
-        t0 = self.trace.start()
+        span = self.trace.start("checkpoint")
         save_run(self, step)
         gc_checkpoints(cfg.ckpt_dir, keep=cfg.ckpt_keep)
         # the record for the round just completed is already emitted, so
         # this lands in the NEXT round's ckpt_wall_s (documented)
-        self.trace.stop("checkpoint", t0,
-                        n_devices=self.state.pool_size)
+        self.trace.stop(span, n_devices=self.state.pool_size)
         if cfg.verbose:
             print(f"[sim] checkpointed step {step} -> {cfg.ckpt_dir}")
 

@@ -114,17 +114,31 @@ class Executor:
 
     # --------------------------------------------------- shared phases
     def _begin(self, t: int):
-        """Phase 1: scenario mutation (+ restack after label reveals).
-        Returns (tick start time, scenario events)."""
+        """Phase 1: scenario mutation (+ restack after data changed).
+        Returns (tick start time, scenario events, the tick's counters:
+        ``restack_bytes``, the bytes of the client stack placed, and
+        ``n_rendered``, the devices whose alt-domain features were
+        first rendered)."""
         eng = self.engine
         t0 = time.time()
         eng.trace.begin_tick(t)
+        span = eng.trace.start("scenario")
+        rendered = len(eng._drift_alt)
         events = eng.scenario.step(eng, t)
+        n_rendered = len(eng._drift_alt) - rendered
+        eng.trace.stop(span, block=eng.state.params)
+        restack_bytes = 0
         if eng._restack:
-            eng.state.clients = eng.pool.place_clients(
-                stack_clients(eng.state.pool))
+            span = eng.trace.start("restack")
+            stack = stack_clients(eng.state.pool)
+            restack_bytes = sum(leaf.nbytes for leaf in
+                                jax.tree_util.tree_leaves(stack))
+            eng.state.clients = eng.pool.place_clients(stack)
+            eng.trace.stop(span, block=eng.state.clients,
+                           nbytes=restack_bytes)
             eng._restack = False
-        return t0, events
+        return t0, events, dict(restack_bytes=int(restack_bytes),
+                                n_rendered=int(n_rendered))
 
     def _gate(self, a: np.ndarray, t: int, drift: float,
               patience: int = 0):
@@ -175,6 +189,7 @@ class Executor:
         (budgeted vs. exhaustive) differ only through genuine staleness,
         which is what benchmarks/sim_drift.py measures."""
         eng, st, cfg = self.engine, self.engine.state, self.engine.cfg
+        span = eng.trace.start("refresh_select")
         dirty = st.dirty_active_pairs()
         if cfg.div_refresh == "all":
             a = st.active_idx
@@ -187,18 +202,21 @@ class Executor:
                 else cfg.div_budget
             pairs = budget_pairs(dirty, st.div_tick, budget)
         if len(pairs) == 0:
+            eng.trace.stop(span, n_dirty=len(dirty), n_pairs=0)
             return len(dirty), 0
         pi, pj = pairs[:, 0], pairs[:, 1]
         ema = np.where(
             np.logical_and(st.div_known[pi, pj], ~st.div_dirty[pi, pj]),
             cfg.div_ema, 0.0)
-        # annotate the pool's divergence event with the dirty backlog —
-        # only the executor knows it (a no-op when tracing is off)
-        eng.trace.with_ctx(n_dirty=len(dirty))
+        keys, h0 = self._pair_content_keys(pairs), self._refresh_h0()
+        eng.trace.stop(span, block=keys, n_dirty=len(dirty),
+                       n_pairs=len(pairs))
         st.div_hat = eng.pool.refresh_divergences(
-            st.div_hat, st.clients, None, pairs, ema=ema,
-            keys=self._pair_content_keys(pairs), h0=self._refresh_h0())
+            st.div_hat, st.clients, None, pairs, ema=ema, keys=keys,
+            h0=h0)
+        span = eng.trace.start("refresh_select")
         st.mark_pairs_estimated(pairs, t)
+        eng.trace.stop(span)
         return len(dirty), len(pairs)
 
     def _measure_kwargs(self, pairs) -> dict:
@@ -242,11 +260,11 @@ class Executor:
         (warm, outer_iters, solve wall seconds)."""
         eng = self.engine
         warm = eng.state.solver is not None
+        span = eng.trace.start("solve")
         res = eng._solve(a)
         eng._install_solution(a, res, t)
-        # the solver measures itself; feed the trace stream directly
-        # (solve keeps its own solver_wall_s field, no WALL_FIELDS entry)
-        eng.trace.add("solve", res.solve_time_s, n_devices=len(a))
+        eng.trace.stop(span, n_devices=len(a))
+        # the row's solver_wall_s keeps the solver's own measurement
         return warm, res.outer_iters, res.solve_time_s
 
     def _link_churn(self) -> float:
@@ -300,11 +318,14 @@ class Executor:
             n_reestimated=int(n_reestimated),
             n_faults=int(n_faults), n_recovered=int(n_recov),
             resume_count=int(eng._resume_count),
+            n_compiled=int(eng.trace.n_compiled),
             # per-phase wall totals popped from the trace accumulators
             # ({} when tracing is off -> the fields keep their 0.0
             # defaults and golden rows are byte-identical)
             **eng.trace.tick_wall_fields(), **extras)
+        span = eng.trace.start("log")
         row = eng.logger.log(record)
+        eng.trace.stop(span)
         st.round = t + 1
         return row, record
 
@@ -316,7 +337,7 @@ class SyncExecutor(Executor):
     def step(self, t: int) -> dict:
         eng = self.engine
         st, cfg = eng.state, eng.cfg
-        t0, events = self._begin(t)
+        t0, events, counts = self._begin(t)
 
         # 2. batched train + measure (one compiled call per pool shard)
         k_round = jax.random.fold_in(eng.key, t)
@@ -362,7 +383,7 @@ class SyncExecutor(Executor):
             transmissions=st.energy.transmissions(
                 st.alpha, thresh=cfg.link_thresh),
             churn=churn, solve_age=solve_age, reason=reason,
-            n_dirty_pairs=n_dirty, n_reestimated=n_reest,
+            n_dirty_pairs=n_dirty, n_reestimated=n_reest, **counts,
             n_trained=int(np.sum(st.labeled_devices[a])))
         if cfg.verbose:
             print(f"[sim] round {t}: active={len(a)} "
@@ -489,7 +510,7 @@ class AsyncGossipExecutor(Executor):
         (P, P) blend matrix would be O(P^2) work for O(pairs) change."""
         eng = self.engine
         st, cfg = eng.state, eng.cfg
-        t0 = eng.trace.start()
+        span = eng.trace.start("transfer")
         used = np.zeros((st.pool_size, st.pool_size))
         blends = []
         for i, j in pairs:
@@ -519,15 +540,14 @@ class AsyncGossipExecutor(Executor):
             st.params = jax.tree_util.tree_map(mix, st.params)
         # async has no global mixture phase; the gossip exchange IS its
         # transfer, so it lands in the same trace phase/wall field
-        eng.trace.stop("transfer", t0, block=st.params,
-                       n_devices=st.pool_size)
+        eng.trace.stop(span, block=st.params, n_devices=st.pool_size)
         return used, len(blends)
 
     # --------------------------------------------------------------- tick
     def step(self, t: int) -> dict:
         eng = self.engine
         st, cfg = eng.state, eng.cfg
-        t0, events = self._begin(t)
+        t0, events, counts = self._begin(t)
 
         # 2. local training on the clock-eligible subset (the pool
         # decides HOW: LocalPool gathers the eligible lanes into a
@@ -577,7 +597,7 @@ class AsyncGossipExecutor(Executor):
             energy=st.energy.energy(used),
             transmissions=n_exchanges, churn=churn,
             solve_age=solve_age, reason=reason,
-            n_dirty_pairs=n_dirty, n_reestimated=n_reest,
+            n_dirty_pairs=n_dirty, n_reestimated=n_reest, **counts,
             n_trained=len(t_idx), trained=[int(i) for i in t_idx],
             gossip=[[int(i), int(j)] for i, j in pairs],
             gossip_topology=cfg.gossip_topology,

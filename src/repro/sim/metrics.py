@@ -63,6 +63,10 @@ scenarios read exactly as before):
                           not yet re-measured)
   n_reestimated    int    pairs the budgeted refresh re-measured this
                           tick (<= div_budget under div_refresh='dirty')
+  n_rendered       int    devices whose alt-domain features were first
+                          rendered this tick (a device's first drift)
+  restack_bytes    int    bytes of the client stack re-stacked and
+                          placed this tick (0 when no data changed)
 
 Fault-tolerance fields (added with the checkpoint/resume + fault
 injection layer; all 0 on fault-free, never-resumed runs):
@@ -76,17 +80,25 @@ injection layer; all 0 on fault-free, never-resumed runs):
                           a checkpoint (0 on an uninterrupted run;
                           constant within one process lifetime)
 
-Per-phase wall clocks (trace subsystem, repro.sim.trace; all 0.0 unless
-``SimConfig.trace`` is on, and all nondeterministic):
+Per-phase wall clocks and the compile count (trace subsystem,
+repro.sim.trace; all 0 unless ``SimConfig.trace`` is on, and all
+nondeterministic):
+  scenario_wall_s  float  wall seconds in the scenario's mutation
+  restack_wall_s   float  wall seconds re-stacking and placing the
+                          client data
   train_wall_s     float  wall seconds in the pool's training phase
   div_wall_s       float  wall seconds in Algorithm-1 estimation
                           (bootstrap + gossip + budgeted refresh)
+  refresh_select_wall_s
+                   float  wall seconds in the budgeted refresh's host
+                          side (pair selection, keys, bookkeeping)
   transfer_wall_s  float  wall seconds in transfer (sync alpha-mixture /
                           async gossip model exchanges)
   eval_wall_s      float  wall seconds in the accuracy sweep
   ckpt_wall_s      float  wall seconds checkpointing — the PREVIOUS
                           round's snapshot (the engine checkpoints after
                           a round's record is emitted)
+  n_compiled       int    programs JAX compiled during the tick
 
 The authoritative field-by-field reference, including which fields are
 nondeterministic, lives in docs/metrics-schema.md (CI checks every
@@ -102,13 +114,16 @@ from typing import IO, List, Optional
 
 # fields excluded when comparing runs: wall clocks (environment-
 # dependent, including the per-phase walls the trace subsystem fills
-# when SimConfig.trace is on) and resume_count (run PROVENANCE — a
+# when SimConfig.trace is on), the compile count (it depends on what
+# the process compiled before) and resume_count (run PROVENANCE — a
 # resumed run must reproduce the uninterrupted trajectory
 # field-for-field except for the counter that says it was resumed)
 NONDETERMINISTIC_FIELDS = ("wall_time_s", "solver_wall_s",
+                           "scenario_wall_s", "restack_wall_s",
                            "train_wall_s", "div_wall_s",
-                           "transfer_wall_s", "eval_wall_s",
-                           "ckpt_wall_s", "resume_count")
+                           "refresh_select_wall_s", "transfer_wall_s",
+                           "eval_wall_s", "ckpt_wall_s", "n_compiled",
+                           "resume_count")
 
 
 @dataclasses.dataclass
@@ -149,19 +164,25 @@ class RoundRecord:
     n_drifted: int = 0
     n_dirty_pairs: int = 0
     n_reestimated: int = 0
+    n_rendered: int = 0
+    restack_bytes: int = 0
     # fault-tolerance fields (0 when no faults are injected / no resume)
     n_faults: int = 0
     n_recovered: int = 0
     resume_count: int = 0
-    # per-phase wall clocks (trace subsystem; 0.0 unless SimConfig.trace
-    # is on — all nondeterministic.  ckpt_wall_s carries the PREVIOUS
-    # round's checkpoint: the engine snapshots after a round's record is
-    # already emitted)
+    # per-phase wall clocks and compile count (trace subsystem; 0 unless
+    # SimConfig.trace is on — all nondeterministic.  ckpt_wall_s carries
+    # the PREVIOUS round's checkpoint: the engine snapshots after a
+    # round's record is already emitted)
+    scenario_wall_s: float = 0.0
+    restack_wall_s: float = 0.0
     train_wall_s: float = 0.0
     div_wall_s: float = 0.0
+    refresh_select_wall_s: float = 0.0
     transfer_wall_s: float = 0.0
     eval_wall_s: float = 0.0
     ckpt_wall_s: float = 0.0
+    n_compiled: int = 0
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)

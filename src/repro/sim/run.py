@@ -136,25 +136,17 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--fault-retries", type=int, default=3,
                    help="bounded-retry budget for transient pool-op "
                         "failures")
-    # trace / autotune (repro.sim.trace)
+    # trace (repro.sim.trace)
     p.add_argument("--trace", action="store_true",
-                   help="record per-phase wall-clock events (fills the "
-                        "*_wall_s metrics fields; zero PRNG impact)")
+                   help="record every phase as an event and a sim.<phase> "
+                        "profiler span (fills the *_wall_s metrics fields "
+                        "and n_compiled; zero PRNG impact)")
     p.add_argument("--trace-out", default=None,
                    help="also stream raw trace events to this JSONL "
                         "file (implies --trace)")
     p.add_argument("--gather-floor", type=int, default=4,
                    help="async subset-gather bucket floor (power-of-two "
-                        "widths start here; an autotuner knob)")
-    p.add_argument("--autotune", action="store_true",
-                   help="before running, search mesh/div-budget/gather-"
-                        "floor/resolve-patience against the fitted cost "
-                        "model and apply the cheapest predicted config")
-    p.add_argument("--autotune-model", default=None,
-                   help="cost model source for --autotune: a "
-                        "BENCH_trace.json or a raw trace .jsonl "
-                        "(default: the repo's committed BENCH_trace"
-                        ".json)")
+                        "widths start here)")
     p.add_argument("--out", default=None,
                    help="JSONL metrics path (default: results/sim/"
                         "<scenario>[-<engine>]-n<devices>-r<rounds>"
@@ -207,25 +199,6 @@ def main(argv=None) -> int:
         trace_path=args.trace_out,
         train_gather_floor=args.gather_floor,
         log_path=out, verbose=not args.quiet)
-    if args.autotune:
-        import dataclasses
-
-        from repro.sim.trace.model import DEFAULT_BENCH, CostModel
-        from repro.sim.trace.tune import autotune
-        model_path = args.autotune_model or DEFAULT_BENCH
-        model = CostModel.from_bench(model_path)
-        tuned = autotune(cfg, model)
-        if tuned["knobs"]:
-            print(f"[sim] autotune ({os.path.basename(model_path)}): "
-                  f"{tuned['knobs']} — predicted "
-                  f"{tuned['predicted_s']:.1f}s vs "
-                  f"{tuned['baseline_s']:.1f}s default "
-                  f"({tuned['n_candidates']} candidates)")
-            cfg = dataclasses.replace(cfg, **tuned["knobs"])
-        else:
-            print(f"[sim] autotune: default config already cheapest "
-                  f"(predicted {tuned['baseline_s']:.1f}s, "
-                  f"{tuned['n_candidates']} candidates)")
     engine = SimulationEngine(cfg)
     rows = engine.run()
 

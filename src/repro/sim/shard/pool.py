@@ -63,9 +63,9 @@ def make_pool(engine: "SimulationEngine") -> "DevicePool":
 def _bucket(n: int, cap: int, floor: int = 4) -> int:
     """Smallest power-of-two >= n (configurable floor, default 4),
     capped at the pool size — the static widths the compact subset step
-    compiles for.  The floor is the ``SimConfig.train_gather_floor``
-    autotuner knob on the training path: a higher floor trades padded
-    lanes for fewer distinct compiled widths."""
+    compiles for.  The floor is ``SimConfig.train_gather_floor`` on the
+    training path: a higher floor trades padded lanes for fewer distinct
+    compiled widths."""
     w = max(1, int(floor))
     while w < n:
         w *= 2
@@ -118,31 +118,31 @@ class DevicePool:
 
     # -- full/masked training step (sync round; async masked fallback)
     def train(self, params, clients, key, active, train_mask=None):
-        t0 = self.engine.trace.start()
+        span = self.engine.trace.start("train")
         out = self._train(params, clients, key, active, train_mask)
-        self.engine.trace.stop("train", t0, block=out,
+        self.engine.trace.stop(span, block=out,
                                n_devices=clients.n_devices)
         return out
 
     # -- async tick: refresh params/eps/acc for the eligible lanes only
     def train_async(self, params, clients, key, active, elig,
                     eps_prev, acc_prev):
-        t0 = self.engine.trace.start()
+        span = self.engine.trace.start("train")
         out = self._train_async(params, clients, key, active, elig,
                                 eps_prev, acc_prev)
-        self.engine.trace.stop("train", t0, block=out,
+        self.engine.trace.stop(span, block=out,
                                n_devices=clients.n_devices)
         return out
 
     def update_divergences(self, div, clients, key, pairs, *, ema=0.0,
                            keys=None, h0=None):
         cfg = self.engine.cfg
-        t0 = self.engine.trace.start()
+        span = self.engine.trace.start("divergence")
         out = _update_divergences(
             div, clients, key, pairs, tau=cfg.div_tau, T=cfg.div_T,
             batch=cfg.batch, lr=cfg.lr, ema=ema,
             values_fn=self._values_fn(), keys=keys, h0=h0)
-        self.engine.trace.stop("divergence", t0, block=out,
+        self.engine.trace.stop(span, block=out,
                                n_devices=clients.n_devices,
                                n_pairs=len(pairs))
         return out
@@ -159,27 +159,26 @@ class DevicePool:
         the pool (the bootstrap).  ``keys``/``h0`` forward the
         content-addressed-key override (see estimate_divergences)."""
         cfg = self.engine.cfg
-        t0 = self.engine.trace.start()
+        span = self.engine.trace.start("divergence")
         out = _update_divergences(
             div, clients, key, pairs, tau=cfg.div_tau, T=cfg.div_T,
             batch=cfg.batch, lr=cfg.lr, ema=ema,
             values_fn=self._targeted_values_fn(), keys=keys, h0=h0)
-        self.engine.trace.stop("divergence", t0, block=out,
+        self.engine.trace.stop(span, block=out,
                                n_devices=clients.n_devices,
                                n_pairs=len(pairs))
         return out
 
     def transfer(self, params, alpha, psi):
-        t0 = self.engine.trace.start()
+        span = self.engine.trace.start("transfer")
         out = self._transfer(params, alpha, psi)
-        self.engine.trace.stop("transfer", t0, block=out,
-                               n_devices=len(psi))
+        self.engine.trace.stop(span, block=out, n_devices=len(psi))
         return out
 
     def accuracies(self, params, clients):
-        t0 = self.engine.trace.start()
+        span = self.engine.trace.start("eval")
         out = self._accuracies(params, clients)
-        self.engine.trace.stop("eval", t0, block=out,
+        self.engine.trace.stop(span, block=out,
                                n_devices=clients.n_devices)
         return out
 
@@ -296,9 +295,6 @@ class LocalPool(DevicePool):
         keys = jax.random.split(key, clients.n_devices)
         w = _bucket(len(g), clients.n_devices,
                     cfg.train_gather_floor)
-        # the trace's train event should carry the COMPACT batch width,
-        # not the mesh-derived lane count — the cost model keys on it
-        self.engine.trace.with_ctx(lanes=w)
         gpad = np.concatenate([g, np.full(w - len(g), g[0], g.dtype)])
         gj = jnp.asarray(gpad)
         sub = lambda a: a[gj]                                 # noqa: E731

@@ -1,29 +1,11 @@
-"""Trace subsystem: per-phase timing events, a fitted cost model,
-what-if replay, and a knob autotuner.
+"""Trace subsystem: the simulator's per-phase spans and events
+(``events.TraceRecorder``; see docs/architecture.md#trace).
 
-Three layers (see docs/architecture.md#trace--replay):
-
-``events``   TraceRecorder — lightweight per-phase wall-clock recording
-             both executors and both pool backends call around train /
-             divergence / transfer / solve / eval / checkpoint.  Zero
-             PRNG consumption; a no-op (and golden-parity preserving)
-             when ``SimConfig.trace`` is off.
-``model``    CostModel — per-phase linear cost functions (e.g.
-             divergence ~ a*pairs + b, train ~ a*ceil(N/mesh) + b)
-             fitted from recorded traces and the committed BENCH_*.json
-             fixtures; JSON-serializable so BENCH_trace.json carries the
-             coefficients.
-``replay``   What-if walker — walks a scenario's control flow
-             (re-solve gating, budgeted refresh, churn, gossip) with the
-             model instead of real execution, predicting per-round and
-             end-to-end wall time for configs never run.
-             CLI: ``python -m repro.sim.replay``.
-``tune``     Autotuner — searches mesh size, ``div_budget``, the train
-             gather bucket floor and ``resolve_patience`` against the
-             model and emits a recommended SimConfig
-             (``python -m repro.sim.run --autotune``).
+Both executors, both pool backends and the engine bracket every phase of
+a tick with the recorder.  Enabled, each phase is a ``sim.<phase>``
+profiler span on the device trace's clock and one event; per-tick totals
+land in the JSONL rows.  Disabled (the default), every call is a no-op.
 """
-from repro.sim.trace.events import TraceRecorder, WALL_FIELDS
-from repro.sim.trace.model import CostModel, phase_features
+from repro.sim.trace.events import PHASES, WALL_FIELDS, TraceRecorder
 
-__all__ = ["TraceRecorder", "WALL_FIELDS", "CostModel", "phase_features"]
+__all__ = ["PHASES", "TraceRecorder", "WALL_FIELDS"]

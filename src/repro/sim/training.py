@@ -32,8 +32,9 @@ def network_step_core(params, clients: StackedClients, keys, active,
     ``keys``: per-device PRNG keys, (N, key_dim) — every lane is
     independent, so callers may gather/shard the device axis freely
     without changing any lane's result."""
-    trained = train_sources(params, clients, keys,
-                            iters=iters, batch=batch, lr=lr)
+    with jax.named_scope("train_scan"):
+        trained = train_sources(params, clients, keys,
+                                iters=iters, batch=batch, lr=lr)
     update = jnp.logical_and(jnp.any(clients.labeled, axis=1),
                              jnp.asarray(active))           # (N,)
     if train_mask is not None:

@@ -32,8 +32,10 @@ def test_nondeterministic_fields_exist_on_record():
     names = {f.name for f in dataclasses.fields(RoundRecord)}
     assert set(NONDETERMINISTIC_FIELDS) <= names
     assert set(NONDETERMINISTIC_FIELDS) == {
-        "wall_time_s", "solver_wall_s", "train_wall_s", "div_wall_s",
-        "transfer_wall_s", "eval_wall_s", "ckpt_wall_s", "resume_count"}
+        "wall_time_s", "solver_wall_s", "scenario_wall_s",
+        "restack_wall_s", "train_wall_s", "div_wall_s",
+        "refresh_select_wall_s", "transfer_wall_s", "eval_wall_s",
+        "ckpt_wall_s", "n_compiled", "resume_count"}
 
 
 def test_roundrecord_jsonl_roundtrip(tmp_path):

@@ -1,30 +1,43 @@
-"""Trace subsystem: recorder semantics, cost-model fit determinism,
-golden parity with tracing enabled, replay/autotune behavior, and the
-committed BENCH_trace.json fixture (refit + replay reproduce it)."""
+"""Trace subsystem: recorder semantics, every phase a profiler span on
+the recorder's clock, phases that tile the round, the counters it
+explains the host with, the layer names the device programs carry, and
+golden parity with tracing enabled."""
+import dataclasses
+import glob
 import json
-import os
+import time
 import types
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src import monitoring
 
+from repro.data.partition import build_network
+from repro.fl import cnn
+from repro.fl.client import init_client_params, stack_clients
+from repro.fl.divergence import pairwise_divergence_values
+from repro.fl.transfer import apply_transfer
+from repro.kernels.alpha_combine.ops import alpha_combine_slab
 from repro.sim.engine import SimConfig, SimulationEngine
-from repro.sim.metrics import strip_nondeterministic
-from repro.sim.trace.events import PHASES, WALL_FIELDS, TraceRecorder
-from repro.sim.trace.model import (CostModel, bench_scale_events,
-                                   phase_features, read_trace)
-from repro.sim.trace.replay import predict_run
-from repro.sim.trace.replay import main as replay_main
-from repro.sim.trace.tune import (PATIENCE_MAX, PATIENCE_MIN, autotune,
-                                  min_budget)
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_TRACE = os.path.join(REPO_ROOT, "BENCH_trace.json")
+from repro.sim.metrics import (NONDETERMINISTIC_FIELDS, RoundRecord,
+                               strip_nondeterministic)
+from repro.sim.trace import events as events_mod
+from repro.sim.trace.events import (PHASES, SPAN_PREFIX, WALL_FIELDS,
+                                    TraceRecorder)
+from repro.sim.training import network_step
 
 #: small-but-real engine settings (the LEAN profile of benchmarks)
 SMOKE = dict(samples_per_device=8, train_iters=2, div_tau=1, div_T=2,
              batch=4, solver_max_outer=2, solver_inner_steps=120,
              resolve_threshold=10.0)
+ROUNDS = 4
+#: under feature-drift, seed 1's first 4 rounds at N=8 hold first
+#: drifts, a repeat drift and a round without any
+SEED = 1
+ENGINES = ("sync", "async-gossip")
+RUNS = [(e, s) for e in ENGINES for s in ("static", "feature-drift")]
 
 
 def _rec(trace=True, trace_path=None, mesh=0):
@@ -33,23 +46,94 @@ def _rec(trace=True, trace_path=None, mesh=0):
     return TraceRecorder(cfg)
 
 
+def _clock(monkeypatch, *stamps_ns):
+    """Feed the recorder these ``perf_counter_ns`` readings in turn."""
+    it = iter(stamps_ns)
+    monkeypatch.setattr(events_mod, "time", types.SimpleNamespace(
+        perf_counter_ns=lambda: next(it)))
+
+
+def _sim_spans(trace_dir):
+    """(start_ns, duration_ns, name) of every ``sim.`` host span in the
+    profile under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    out = []
+    for path in glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True):
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                out.extend((ev.start_ns, ev.duration_ns, ev.name)
+                           for line in plane.lines for ev in line.events
+                           if ev.name.startswith(SPAN_PREFIX))
+    return sorted(out)
+
+
+def _profiled_run(engine, scenario, trace_dir):
+    """ROUNDS traced ticks at N=8 under the profiler: the recorder's
+    events, the rows, each step's (start, end) ns, the bytes handed to
+    ``place_clients`` per tick, and the profile's ``sim.`` spans."""
+    eng = SimulationEngine(SimConfig(
+        scenario=scenario, engine=engine, devices=8, rounds=ROUNDS,
+        seed=SEED, trace=True, verbose=False, **SMOKE))
+    placed = {}
+    place = eng.pool.place_clients
+
+    def spy(clients):
+        placed[eng.trace.tick] = sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(clients))
+        return place(clients)
+
+    eng.pool.place_clients = spy
+    rows, steps = [], {}
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        for t in range(ROUNDS):
+            t0 = time.perf_counter_ns()
+            rows.append(eng.step(t))
+            steps[t] = (t0, time.perf_counter_ns())
+    finally:
+        jax.profiler.stop_trace()
+    eng.logger.close()
+    eng.trace.close()
+    return types.SimpleNamespace(
+        events=sorted(eng.trace.events, key=lambda e: e["t0_ns"]),
+        rows=rows, steps=steps, placed=placed,
+        spans=_sim_spans(trace_dir), rendered=len(eng._drift_alt))
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    cache = {}
+
+    def get(engine, scenario):
+        if (engine, scenario) not in cache:
+            cache[engine, scenario] = _profiled_run(
+                engine, scenario, tmp_path_factory.mktemp("xplane"))
+        return cache[engine, scenario]
+    return get
+
+
 # ------------------------------------------------------------- recorder
 def test_recorder_disabled_is_noop():
+    before = list(monitoring.get_event_duration_listeners())
     rec = _rec(trace=False)
-    assert rec.start() is None
-    rec.stop("train", None, n_devices=8)      # must not record
-    rec.add("train", 1.0)
-    rec.with_ctx(lanes=4)
+    assert rec.start("train") is None
+    rec.stop(None, block=object(), n_devices=8)   # no block, no record
     assert rec.events == []
     assert rec.tick_wall_fields() == {}       # fields keep 0.0 defaults
+    assert rec.n_compiled == 0
+    assert list(monitoring.get_event_duration_listeners()) == before
 
 
-def test_recorder_accumulates_and_pops_per_tick():
+def test_recorder_accumulates_and_pops_per_tick(monkeypatch):
+    _clock(monkeypatch, 0, 500_000_000, 600_000_000, 850_000_000,
+           900_000_000, 1_000_000_000)
     rec = _rec()
     rec.begin_tick(0)
-    rec.add("train", 0.5, n_devices=8)
-    rec.add("train", 0.25, n_devices=8)
-    rec.add("divergence", 0.1, n_pairs=28)
+    for phase, ctx in (("train", {"n_devices": 8}),
+                       ("train", {"n_devices": 8}),
+                       ("divergence", {"n_pairs": 28})):
+        rec.stop(rec.start(phase), **ctx)
+    rec.close()
     fields = rec.tick_wall_fields()
     assert fields["train_wall_s"] == pytest.approx(0.75)
     assert fields["div_wall_s"] == pytest.approx(0.1)
@@ -58,34 +142,43 @@ def test_recorder_accumulates_and_pops_per_tick():
     assert rec.tick_wall_fields()["train_wall_s"] == 0.0
     assert [e["phase"] for e in rec.events] == ["train", "train",
                                                 "divergence"]
+    assert [e["t0_ns"] for e in rec.events] == [0, 600_000_000,
+                                                900_000_000]
     assert rec.events[2]["n_pairs"] == 28 and rec.events[0]["tick"] == 0
-
-
-def test_recorder_ctx_merges_into_next_event_only():
-    rec = _rec()
-    rec.with_ctx(n_dirty=5, lanes=8)
-    rec.add("divergence", 0.2, n_pairs=5)
-    rec.add("divergence", 0.2, n_pairs=5)
-    assert rec.events[0]["n_dirty"] == 5 and rec.events[0]["lanes"] == 8
-    assert "n_dirty" not in rec.events[1]
 
 
 def test_recorder_stop_timing_and_trace_file(tmp_path):
     path = str(tmp_path / "trace.jsonl")
     rec = _rec(trace_path=path)
-    t0 = rec.start()
-    assert t0 is not None
-    rec.stop("eval", t0, n_devices=4)
+    span = rec.start("eval")
+    assert span is not None
+    rec.stop(span, n_devices=4)
     rec.close()
-    back = read_trace(path)
+    with open(path) as f:
+        back = [json.loads(line) for line in f]
     assert len(back) == 1 and back[0]["phase"] == "eval"
     assert back[0]["seconds"] >= 0.0 and back[0]["n_devices"] == 4
+    assert isinstance(back[0]["t0_ns"], int)
     assert back == rec.events
+
+
+def test_compile_listener_lives_until_close():
+    before = len(monitoring.get_event_duration_listeners())
+    rec = _rec()
+    assert len(monitoring.get_event_duration_listeners()) == before + 1
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(5.0)).block_until_ready()
+    assert rec.n_compiled >= 1
+    rec.close()
+    rec.close()                               # closing twice is harmless
+    assert len(monitoring.get_event_duration_listeners()) == before
 
 
 def test_every_wall_field_phase_is_a_known_phase():
     assert set(WALL_FIELDS) < set(PHASES)
-    assert "solve" in PHASES and "solve" not in WALL_FIELDS
+    assert {"solve", "log"} <= set(PHASES) - set(WALL_FIELDS)
+    fields = {f.name for f in dataclasses.fields(RoundRecord)}
+    assert set(WALL_FIELDS.values()) | {"n_compiled"} <= \
+        fields & set(NONDETERMINISTIC_FIELDS)
 
 
 def test_engine_cfg_validation():
@@ -95,87 +188,143 @@ def test_engine_cfg_validation():
         SimConfig(devices=4, rounds=1, train_gather_floor=0)
 
 
-# ------------------------------------------------------------ cost model
-def _synthetic_events():
-    """Known linear costs: train 0.05*lanes + 0.2 (tick-0 pays +3.0 jit),
-    divergence 0.01*pairs + 0.1, solve 0.02*n + 0.5."""
-    evs = []
-    for tick in range(3):
-        for n in (8, 16, 32):
-            extra = 3.0 if tick == 0 else 0.0
-            evs.append({"phase": "train", "tick": tick, "mesh": 0,
-                        "n_devices": n, "seconds": 0.05 * n + 0.2 + extra})
-            pairs = n * (n - 1) // 2
-            evs.append({"phase": "divergence", "tick": tick, "mesh": 0,
-                        "n_devices": n, "n_pairs": pairs,
-                        "seconds": 0.01 * pairs + 0.1})
-            evs.append({"phase": "solve", "tick": tick, "mesh": 0,
-                        "n_devices": n, "seconds": 0.02 * n + 0.5})
-    return evs
+# ------------------------------------------------- spans on one clock
+@pytest.mark.parametrize("engine,scenario", RUNS)
+def test_each_event_is_one_profiler_span_on_the_same_clock(
+        profiled, engine, scenario):
+    run = profiled(engine, scenario)
+    assert [name for _, _, name in run.spans] == \
+        [SPAN_PREFIX + e["phase"] for e in run.events]
+    offsets = []
+    for (start, dur, _), e in zip(run.spans, run.events):
+        assert abs(dur / 1e9 - e["seconds"]) <= 0.05 * e["seconds"] + 1e-4
+        offsets.append(start - e["t0_ns"])
+    assert max(offsets) - min(offsets) < 1_000_000
 
 
-def test_fit_recovers_known_linear_costs():
-    model = CostModel.fit(_synthetic_events())
-    tr = model.phases["train"]
-    assert tr["coef"] == pytest.approx([0.05, 0.2], abs=1e-9)
-    assert tr["first_extra"] == pytest.approx(3.0, abs=1e-9)
-    dv = model.phases["divergence"]
-    assert dv["coef"] == pytest.approx([0.01, 0.1], abs=1e-9)
-    assert dv["first_extra"] == pytest.approx(0.0, abs=1e-9)
-    # prediction matches the generator exactly
-    got = model.predict("train", {"n_devices": 64, "mesh": 0})
-    assert got == pytest.approx(0.05 * 64 + 0.2)
-    got0 = model.predict("train", {"n_devices": 64, "mesh": 0},
-                         first=True)
-    assert got0 == pytest.approx(0.05 * 64 + 0.2 + 3.0)
-    # unseen phase predicts 0, not KeyError
-    assert model.predict("checkpoint", {"n_devices": 64}) == 0.0
+@pytest.mark.parametrize("engine,scenario", RUNS)
+def test_phases_tile_the_round(profiled, engine, scenario):
+    """Per round the phases are disjoint and inside the step, and the
+    phases before the row is built fit in its ``wall_time_s``."""
+    run = profiled(engine, scenario)
+    for row in run.rows:
+        t = row["round"]
+        evs = [e for e in run.events if e["tick"] == t]
+        end, hi = run.steps[t]
+        for e in evs:
+            assert e["t0_ns"] >= end, (t, e["phase"])
+            end = e["t0_ns"] + round(e["seconds"] * 1e9)
+        assert end <= hi
+        body = sum(e["seconds"] for e in evs if e["phase"] != "log")
+        assert body <= row["wall_time_s"]
+        assert {"scenario", "train", "refresh_select", "eval",
+                "log"} <= {e["phase"] for e in evs}
 
 
-def test_fit_is_deterministic_and_roundtrips():
-    evs = _synthetic_events()
-    a, b = CostModel.fit(evs), CostModel.fit(evs)
-    assert a.to_dict() == b.to_dict()
-    back = CostModel.from_dict(json.loads(json.dumps(a.to_dict())))
-    assert back.to_dict() == a.to_dict()
+def test_log_phase_is_recorded_once_per_round_after_the_row(profiled):
+    for engine in ENGINES:
+        run = profiled(engine, "static")
+        for row in run.rows:
+            evs = [e for e in run.events if e["tick"] == row["round"]]
+            assert [e["phase"] for e in evs].count("log") == 1
+            assert evs[-1]["phase"] == "log"
 
 
-def test_negative_slope_is_clamped():
-    # seconds DECREASE with the feature: the slope must clamp to 0 and
-    # the intercept absorb the mean (never a negative prediction)
-    evs = [{"phase": "train", "tick": 1, "mesh": 0, "n_devices": n,
-            "seconds": 2.0 - 0.01 * n} for n in (8, 16, 32, 64)]
-    model = CostModel.fit(evs)
-    coef = model.phases["train"]["coef"]
-    assert coef[0] == 0.0 and coef[1] > 0
-    assert model.predict("train", {"n_devices": 4096, "mesh": 0}) > 0
+def test_tracing_off_opens_no_span_and_registers_no_listener(tmp_path):
+    before = list(monitoring.get_event_duration_listeners())
+    eng = SimulationEngine(SimConfig(
+        scenario="feature-drift", devices=8, rounds=2, seed=SEED,
+        verbose=False, **SMOKE))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        rows = [eng.step(t) for t in range(2)]
+    finally:
+        jax.profiler.stop_trace()
+    assert _sim_spans(tmp_path) == []
+    assert list(monitoring.get_event_duration_listeners()) == before
+    assert eng.trace.events == []
+    assert all(r["n_compiled"] == 0 and r["scenario_wall_s"] == 0.0
+               for r in rows)
 
 
-def test_phase_features_lanes_override_and_mesh():
-    # mesh-derived lanes: ceil(64 / 8) = 8
-    f = phase_features("train", {"n_devices": 64, "mesh": 8})
-    assert f[0] == 8
-    # explicit lanes (async subset-gather bucket) wins over mesh
-    f = phase_features("train", {"n_devices": 64, "mesh": 8, "lanes": 16})
-    assert f[0] == 16
-    f = phase_features("transfer", {"n_devices": 64, "mesh": 8})
-    assert f[0] == 64 * 8
+# ------------------------------------------------------------ counters
+@pytest.mark.parametrize("engine", ENGINES)
+def test_restack_bytes_is_the_placed_stack(profiled, engine):
+    run = profiled(engine, "feature-drift")
+    got = {r["round"]: r["restack_bytes"] for r in run.rows}
+    assert got == {t: run.placed.get(t, 0) for t in got}
+    assert any(got.values()) and not all(got.values())
+    for row in run.rows:
+        assert bool(row["restack_bytes"]) == (row["n_drifted"] > 0)
+    # the stack's size follows from its shapes: 8 devices x 8 samples
+    # of 28x28x3 float32, four (8, 8) int32/bool arrays, 8 counts
+    assert max(got.values()) == 8 * 8 * (28 * 28 * 3 * 4 + 4 + 1 + 1
+                                         + 4) + 8 * 4
 
 
-def test_bench_scale_events_tolerates_both_schemas(tmp_path):
-    rows = [{"dry": True, "phase": "train", "n": 256, "mesh": 8,
-             "steady_s": 1.5},
-            {"dry": True, "phase": "divergence_64pairs", "n": 256,
-             "mesh": 8, "steady_s": 0.4},
-            {"dry": False, "phase": "train", "n": 256, "steady_s": 9.9}]
-    bare, stamped = tmp_path / "a.json", tmp_path / "b.json"
-    bare.write_text(json.dumps(rows))
-    stamped.write_text(json.dumps({"benchmark": "x", "rows": rows}))
-    for path in (bare, stamped):
-        evs = bench_scale_events(str(path))
-        assert len(evs) == 2                     # wet row filtered out
-        assert evs[0]["phase"] == "train" and evs[0]["n_devices"] == 256
-        assert evs[1]["phase"] == "divergence" and evs[1]["n_pairs"] == 64
+@pytest.mark.parametrize("engine", ENGINES)
+def test_n_rendered_counts_first_drift_renders(profiled, engine):
+    run = profiled(engine, "feature-drift")
+    seen, repeats = set(), 0
+    for row in run.rows:
+        devs = {e["device"] for e in row["events"]
+                if e.get("event") == "feature_drift"}
+        assert row["n_rendered"] == len(devs - seen)
+        repeats += len(devs & seen)
+        seen |= devs
+    assert repeats, "no device drifted twice: nothing tells them apart"
+    assert sum(r["n_rendered"] for r in run.rows) == run.rendered > 0
+
+
+def test_n_compiled_counts_the_compiles_of_the_round():
+    # shapes no other test compiles, so round 0 compiles its programs
+    rows = SimulationEngine(SimConfig(
+        scenario="static", devices=7, rounds=3, seed=0, trace=True,
+        verbose=False, **dict(SMOKE, samples_per_device=11))).run()
+    assert rows[0]["n_compiled"] > 0
+    assert [r["n_compiled"] for r in rows[1:]] == [0, 0]
+
+
+# ------------------------------------------- layer names in the programs
+def _clients(n=2, samples=4):
+    return stack_clients(build_network("M//MM", num_devices=n,
+                                       samples_per_device=samples, seed=0))
+
+
+def _lower_train():
+    clients = _clients()
+    return network_step.lower(
+        init_client_params(2, jax.random.PRNGKey(0)), clients,
+        jax.random.PRNGKey(1), np.ones(2, bool), iters=2, batch=4,
+        lr=0.01)
+
+
+def _lower_pairs():
+    h0 = cnn.cnn_init(jax.random.PRNGKey(0), num_classes=2)
+    idx = jnp.zeros(1, jnp.int32)
+    return pairwise_divergence_values.lower(
+        h0, _clients(), idx, idx + 1, jax.random.split(
+            jax.random.PRNGKey(1), 1), tau=1, T=2, batch=4, lr=0.01)
+
+
+def _lower_combine_xla():
+    params = init_client_params(3, jax.random.PRNGKey(0))
+    return jax.jit(apply_transfer).lower(params, jnp.eye(3),
+                                         jnp.ones(3))
+
+
+def _lower_combine_pallas():
+    return jax.jit(alpha_combine_slab).lower(jnp.ones((8, 256)),
+                                             jnp.ones((8, 8)))
+
+
+@pytest.mark.parametrize("scope,lower", [
+    ("train_scan", _lower_train), ("pair_scan", _lower_pairs),
+    ("transfer_combine", _lower_combine_xla),
+    ("transfer_combine", _lower_combine_pallas)],
+    ids=["train", "pairs", "combine-xla", "combine-pallas"])
+def test_device_programs_carry_their_layer_name(scope, lower):
+    assert f"/{scope}/" in lower().as_text(debug_info=True)
 
 
 # ---------------------------------------------------------- golden parity
@@ -195,117 +344,6 @@ def test_trace_on_off_golden_parity(tmp_path):
             assert walls, "traced run has no train wall clocks"
     assert json.dumps(runs[0], sort_keys=True) == \
         json.dumps(runs[1], sort_keys=True)
-
-
-# ----------------------------------------------------------------- replay
-def test_replay_is_deterministic_and_scales():
-    model = CostModel.fit(_synthetic_events())
-    cfg = SimConfig(scenario="static", devices=64, rounds=5, seed=0,
-                    verbose=False, **SMOKE)
-    a, b = predict_run(cfg, model), predict_run(cfg, model)
-    assert a == b
-    assert a["total_s"] == pytest.approx(
-        sum(r["total_s"] for r in a["per_round"]))
-    # round 0 carries the all-pairs bootstrap + first_extra: strictly
-    # more expensive than a steady round
-    assert a["round0_s"] > a["steady_mean_s"]
-    # bigger networks predict longer walls under positive slopes
-    big = predict_run(SimConfig(scenario="static", devices=128, rounds=5,
-                                seed=0, verbose=False, **SMOKE), model)
-    assert big["total_s"] > a["total_s"]
-
-
-def test_replay_drift_budget_moves_divergence_load():
-    model = CostModel.fit(_synthetic_events())
-    kw = dict(scenario="feature-drift", devices=32, rounds=6, seed=0,
-              verbose=False, feature_drift_p=0.5, feature_drift_frac=0.25,
-              feature_drift_step=0.25, **SMOKE)
-    full = predict_run(SimConfig(div_budget=-1, **kw), model)
-    tight = predict_run(SimConfig(div_budget=4, **kw), model)
-    assert tight["phase_totals_s"]["divergence"] < \
-        full["phase_totals_s"]["divergence"]
-
-
-def test_replay_cli_fits_a_jsonl_trace(tmp_path, capsys):
-    path = str(tmp_path / "t.jsonl")
-    with open(path, "w") as f:
-        for e in _synthetic_events():
-            f.write(json.dumps(e) + "\n")
-    rc = replay_main(["--scenario", "static", "--n", "32", "--rounds",
-                      "3", "--model", path])
-    assert rc == 0
-    out = capsys.readouterr().out
-    assert "end-to-end" in out and "WARNING" in out  # no transfer/eval fit
-
-
-# --------------------------------------------------------------- autotune
-def test_autotune_never_worse_and_respects_guardrails():
-    model = CostModel.fit(_synthetic_events())
-    cfg = SimConfig(scenario="static", engine="async-gossip", devices=64,
-                    rounds=50, seed=0, verbose=False, **SMOKE)
-    out = autotune(cfg, model)
-    assert out["predicted_s"] <= out["baseline_s"]
-    assert out["n_candidates"] > 1
-    pat = out["knobs"].get("resolve_patience")
-    if pat is not None:
-        assert PATIENCE_MIN <= pat <= PATIENCE_MAX
-    # mesh never extrapolates beyond the fitted meshes by default
-    mesh = out["knobs"].get("mesh")
-    assert mesh is None or mesh in model.known_meshes() | {cfg.mesh}
-
-
-def test_autotune_budget_floor_covers_drift_rate():
-    model = CostModel.fit(_synthetic_events())
-    cfg = SimConfig(scenario="feature-drift", devices=32, rounds=20,
-                    seed=0, verbose=False, feature_drift_p=0.5,
-                    feature_drift_frac=0.25, feature_drift_step=0.25,
-                    **SMOKE)
-    floor = min_budget(cfg)
-    assert floor > 0
-    out = autotune(cfg, model)
-    b = out["knobs"].get("div_budget", cfg.div_budget)
-    eff = cfg.devices if b == -1 else \
-        (cfg.devices * (cfg.devices - 1) // 2 if b == 0 else b)
-    assert eff >= floor, "tuned budget starves the drift refresh"
-    assert out["min_div_budget"] == floor
-
-
-# ------------------------------------------------- committed BENCH fixture
-needs_bench = pytest.mark.skipif(
-    not os.path.exists(BENCH_TRACE),
-    reason="BENCH_trace.json not generated yet (benchmarks/sim_trace "
-           "--full --write-bench)")
-
-
-@needs_bench
-def test_bench_trace_fixture_refit_matches_committed_model():
-    with open(BENCH_TRACE) as f:
-        bench = json.load(f)
-    refit = CostModel.fit(bench["events"])
-    committed = CostModel.from_bench(BENCH_TRACE)
-    assert set(refit.phases) == set(committed.phases)
-    for phase, spec in committed.phases.items():
-        assert refit.phases[phase]["coef"] == \
-            pytest.approx(spec["coef"], rel=1e-9, abs=1e-12)
-
-
-@needs_bench
-def test_bench_trace_fixture_replay_reproduces_prediction():
-    from benchmarks.sim_trace import _cfg
-    with open(BENCH_TRACE) as f:
-        bench = json.load(f)
-    pred_rec = bench["prediction"]
-    model = CostModel.from_bench(BENCH_TRACE)
-    pred = predict_run(_cfg(pred_rec["n"], pred_rec["rounds"]), model)
-    assert pred["total_s"] == pytest.approx(
-        pred_rec["predicted"]["total_s"], rel=1e-6)
-    assert pred["round0_s"] == pytest.approx(
-        pred_rec["predicted"]["round0_s"], rel=1e-6)
-    # the committed held-out measurement landed inside the error bar
-    assert pred_rec["err_frac"] <= bench["err_bar"]
-    # and the committed autotune demo beat the hand-set default
-    tuned = bench["autotune"]
-    assert tuned["knobs"] and tuned["predicted_s"] < tuned["baseline_s"]
 
 
 # ------------------------------------------------------- bench artifacts
