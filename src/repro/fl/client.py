@@ -39,9 +39,10 @@ class StackedClients:
         return self.x.shape[0]
 
 
-def stack_clients(devices: List[DeviceData]) -> StackedClients:
-    n_max = max(d.n for d in devices)
-
+def pad_clients(devices: List[DeviceData], n_max: int) -> StackedClients:
+    """The stack layout, on the host: each device's data padded to
+    ``n_max`` rows, as numpy leaves.  ``stack_clients`` pads the whole
+    pool with it; the pool's row write pads the changed devices."""
     def pad(a, fill=0):
         out = np.full((len(devices), n_max) + a[0].shape[1:], fill,
                       dtype=a[0].dtype)
@@ -50,13 +51,24 @@ def stack_clients(devices: List[DeviceData]) -> StackedClients:
         return out
 
     return StackedClients(
-        x=jnp.asarray(pad([d.images for d in devices], 0.0)),
-        y=jnp.asarray(pad([d.labels for d in devices], -1)),
-        labeled=jnp.asarray(pad([d.labeled_mask for d in devices], False)),
-        valid=jnp.asarray(pad([np.ones(d.n, bool) for d in devices], False)),
-        true_y=jnp.asarray(pad([d.true_labels for d in devices], -1)),
-        counts=jnp.asarray([d.n for d in devices], jnp.int32),
+        x=pad([d.images for d in devices], 0.0),
+        y=pad([d.labels for d in devices], -1),
+        labeled=pad([d.labeled_mask for d in devices], False),
+        valid=pad([np.ones(d.n, bool) for d in devices], False),
+        true_y=pad([d.true_labels for d in devices], -1),
+        counts=np.asarray([d.n for d in devices], np.int32),
     )
+
+
+def stack_clients(devices: List[DeviceData]) -> StackedClients:
+    return jax.tree_util.tree_map(
+        jnp.asarray, pad_clients(devices, max(d.n for d in devices)))
+
+
+def set_client_rows(clients: StackedClients, idx, rows: StackedClients):
+    """Rows ``idx`` of every leaf of ``clients`` set to ``rows``."""
+    return jax.tree_util.tree_map(lambda a, r: a.at[idx].set(r),
+                                  clients, rows)
 
 
 # ------------------------------------------------------------- local SGD

@@ -159,6 +159,7 @@ def chunked_pair_lanes(pi, pj, keys, width: int, call, *,
     must divide its lanes over the mesh); False keeps the historical
     local behavior of compiling a small batch at its natural size."""
     npairs = len(pi)
+    keys = np.asarray(keys)     # sliced and padded on the host: no program
     out = np.zeros(npairs)
     for c0 in range(0, npairs, width):
         ci = pi[c0:c0 + width]
@@ -168,8 +169,7 @@ def chunked_pair_lanes(pi, pj, keys, width: int, call, *,
         if pad:
             ci = np.concatenate([ci, np.full(pad, ci[0])])
             cj = np.concatenate([cj, np.full(pad, cj[0])])
-            ck = jnp.concatenate([ck, jnp.broadcast_to(
-                ck[0], (pad,) + ck.shape[1:])])
+            ck = np.concatenate([ck, np.repeat(ck[:1], pad, axis=0)])
         vals = np.asarray(call(ci, cj, ck))
         out[c0:c0 + width - pad] = vals[:width - pad]
     return out

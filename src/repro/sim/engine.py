@@ -41,7 +41,8 @@ from repro.data.digits import DOMAINS, render_images
 from repro.data.partition import (DeviceData, build_network,
                                   interpolate_features, make_device,
                                   reveal_labels)
-from repro.fl.client import init_client_params, stack_clients
+from repro.fl.client import (init_client_params, pad_clients,
+                             stack_clients)
 from repro.fl.transfer import column_normalize
 from repro.sim.executors import get_executor
 from repro.sim.metrics import MetricsLogger
@@ -311,7 +312,8 @@ class SimulationEngine:
             div_tick=np.full((p, p), -1, int),
             energy=EnergyModel.sample(p, np.random.default_rng(cfg.seed)),
             psi=np.zeros(p), alpha=np.zeros((p, p)))
-        self._restack = False
+        #: pool slots whose data changed since the stack was last written
+        self._dirty_clients: set = set()
         self._membership_dirty = False
         self._prev_links: set = set()
         self._energy_cum = 0.0
@@ -363,7 +365,7 @@ class SimulationEngine:
                       rng: np.random.Generator):
         self.state.pool[device] = reveal_labels(self.state.pool[device],
                                                 frac, rng)
-        self._restack = True
+        self._dirty_clients.add(int(device))
 
     def set_tick_period(self, device: int, period: int):
         """Re-rate one device's local clock (no-op under executors that
@@ -412,10 +414,32 @@ class SimulationEngine:
                                 cur.labeled_mask, cur.domain_ids,
                                 cur.true_labels)
         st.mark_pairs_dirty(j)
-        self._restack = True
+        self._dirty_clients.add(j)
         return self._drift_domain[j]
 
     # ------------------------------------------------------------ internals
+    def _restack(self) -> Tuple[int, int]:
+        """Bring the placed client stack up to ``state.pool``: the
+        changed devices' rows are written into it, unless the pool's
+        padded length no longer matches the stack's, which takes a
+        full stack and placement.  Either way the stack equals
+        ``stack_clients(state.pool)``.  Returns (bytes, rows) written."""
+        st = self.state
+        rows = sorted(self._dirty_clients)
+        self._dirty_clients.clear()
+        n_max = max(d.n for d in st.pool)
+        if n_max != st.clients.x.shape[1]:
+            stack = stack_clients(st.pool)
+            st.clients = self.pool.place_clients(stack)
+            rows = range(st.pool_size)
+        else:
+            stack = pad_clients([st.pool[j] for j in rows], n_max)
+            st.clients = self.pool.write_client_rows(st.clients, rows,
+                                                     stack)
+        nbytes = sum(leaf.nbytes
+                     for leaf in jax.tree_util.tree_leaves(stack))
+        return nbytes, len(rows)
+
     def _reseed_device(self, j: int):
         """Churn-robust transfer: a (re)joining device adopts the
         consensus source mixture of the last solved assignment (the mean

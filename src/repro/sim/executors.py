@@ -57,13 +57,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl.client import stack_clients
 from repro.fl.divergence import budget_pairs
 from repro.sim.clock import DeviceClocks
 from repro.sim.metrics import RoundRecord
+from repro.sim.shard.pool import PAIR_CHUNK
 
 if TYPE_CHECKING:                                   # no import cycle
     from repro.sim.engine import SimulationEngine
+
+
+@jax.jit
+def _fold_pair_keys(base, lo, hi):
+    """``fold_in(fold_in(base, lo), hi)``, lane by lane."""
+    return jax.vmap(lambda i, j: jax.random.fold_in(
+        jax.random.fold_in(base, i), j))(lo, hi)
 
 EXECUTORS: Dict[str, Type["Executor"]] = {}
 
@@ -116,9 +123,9 @@ class Executor:
     def _begin(self, t: int):
         """Phase 1: scenario mutation (+ restack after data changed).
         Returns (tick start time, scenario events, the tick's counters:
-        ``restack_bytes``, the bytes of the client stack placed, and
-        ``n_rendered``, the devices whose alt-domain features were
-        first rendered)."""
+        ``restack_bytes`` and ``restack_rows``, what the restack wrote
+        into the client stack, and ``n_rendered``, the devices whose
+        alt-domain features were first rendered)."""
         eng = self.engine
         t0 = time.time()
         eng.trace.begin_tick(t)
@@ -127,17 +134,14 @@ class Executor:
         events = eng.scenario.step(eng, t)
         n_rendered = len(eng._drift_alt) - rendered
         eng.trace.stop(span, block=eng.state.params)
-        restack_bytes = 0
-        if eng._restack:
+        restack_bytes = restack_rows = 0
+        if eng._dirty_clients:
             span = eng.trace.start("restack")
-            stack = stack_clients(eng.state.pool)
-            restack_bytes = sum(leaf.nbytes for leaf in
-                                jax.tree_util.tree_leaves(stack))
-            eng.state.clients = eng.pool.place_clients(stack)
+            restack_bytes, restack_rows = eng._restack()
             eng.trace.stop(span, block=eng.state.clients,
                            nbytes=restack_bytes)
-            eng._restack = False
         return t0, events, dict(restack_bytes=int(restack_bytes),
+                                restack_rows=int(restack_rows),
                                 n_rendered=int(n_rendered))
 
     def _gate(self, a: np.ndarray, t: int, drift: float,
@@ -231,17 +235,22 @@ class Executor:
         return dict(keys=self._pair_content_keys(np.asarray(pairs)),
                     h0=self._refresh_h0())
 
-    def _pair_content_keys(self, pairs: np.ndarray):
-        """(K, key_dim) content-addressed keys:
+    def _pair_content_keys(self, pairs: np.ndarray) -> np.ndarray:
+        """(K, key_dim) content-addressed keys, on the host:
         ``fold_in(fold_in(refresh_stream, min(i, j)), max(i, j))`` —
-        symmetric in the pair, independent of batch composition."""
+        symmetric in the pair, independent of batch composition.  They
+        are derived in lanes of ``PAIR_CHUNK`` (the last one padded), so
+        every K runs the one compiled program."""
         base = jax.random.fold_in(
             jax.random.PRNGKey(self.engine.cfg.seed), 2 ** 20)
-        lo = np.minimum(pairs[:, 0], pairs[:, 1])
-        hi = np.maximum(pairs[:, 0], pairs[:, 1])
-        return jax.vmap(lambda i, j: jax.random.fold_in(
-            jax.random.fold_in(base, i), j))(jnp.asarray(lo),
-                                             jnp.asarray(hi))
+        n = len(pairs)
+        lanes = np.zeros((2, -(-max(n, 1) // PAIR_CHUNK) * PAIR_CHUNK),
+                         np.int32)
+        lanes[0, :n] = np.minimum(pairs[:, 0], pairs[:, 1])
+        lanes[1, :n] = np.maximum(pairs[:, 0], pairs[:, 1])
+        return np.concatenate([
+            np.asarray(_fold_pair_keys(base, *lanes[:, s:s + PAIR_CHUNK]))
+            for s in range(0, lanes.shape[1], PAIR_CHUNK)])[:n]
 
     def _refresh_h0(self):
         """The per-run shared classifier init of the refresh stream

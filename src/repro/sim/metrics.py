@@ -65,8 +65,12 @@ scenarios read exactly as before):
                           tick (<= div_budget under div_refresh='dirty')
   n_rendered       int    devices whose alt-domain features were first
                           rendered this tick (a device's first drift)
-  restack_bytes    int    bytes of the client stack re-stacked and
-                          placed this tick (0 when no data changed)
+  restack_bytes    int    bytes written into the client stack this
+                          tick: the changed devices' rows, or the whole
+                          stack on a full restack (0 when no data
+                          changed)
+  restack_rows     int    rows of the client stack written this tick
+                          (the pool size on a full restack)
 
 Fault-tolerance fields (added with the checkpoint/resume + fault
 injection layer; all 0 on fault-free, never-resumed runs):
@@ -84,8 +88,8 @@ Per-phase wall clocks and the compile count (trace subsystem,
 repro.sim.trace; all 0 unless ``SimConfig.trace`` is on, and all
 nondeterministic):
   scenario_wall_s  float  wall seconds in the scenario's mutation
-  restack_wall_s   float  wall seconds re-stacking and placing the
-                          client data
+  restack_wall_s   float  wall seconds writing changed data into the
+                          client stack
   train_wall_s     float  wall seconds in the pool's training phase
   div_wall_s       float  wall seconds in Algorithm-1 estimation
                           (bootstrap + gossip + budgeted refresh)
@@ -166,6 +170,7 @@ class RoundRecord:
     n_reestimated: int = 0
     n_rendered: int = 0
     restack_bytes: int = 0
+    restack_rows: int = 0
     # fault-tolerance fields (0 when no faults are injected / no resume)
     n_faults: int = 0
     n_recovered: int = 0

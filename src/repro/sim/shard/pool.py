@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.fl.client import set_client_rows
 from repro.fl.divergence import (chunked_pair_lanes,
                                  pairwise_divergence_values)
 from repro.fl.divergence import update_divergences as _update_divergences
@@ -53,6 +54,8 @@ if TYPE_CHECKING:                                   # no import cycle
 #: per-shard cap on the vmapped pair-classifier batch (matches the local
 #: estimator's pair_chunk so working-set bounds carry over per shard)
 PAIR_CHUNK = 256
+#: rows of the client stack one row write sets (``write_client_rows``)
+ROW_BLOCK = 8
 
 
 def make_pool(engine: "SimulationEngine") -> "DevicePool":
@@ -107,6 +110,10 @@ class DevicePool:
 
     def __init__(self, engine: "SimulationEngine"):
         self.engine = engine
+        #: the row write, built for the placed stack's sharding
+        self._row_write = None
+        #: widths the row-targeted refresh ran, per axis ("rows", "lanes")
+        self._widths: dict = {}
 
     # The public phase methods are TEMPLATE METHODS: they bracket the
     # backend implementation (``_train`` / ``_train_async`` /
@@ -188,6 +195,30 @@ class DevicePool:
         keeps it on the default device."""
         return clients
 
+    def write_client_rows(self, clients, rows, block):
+        """Write the changed devices into the placed stack: ``block``
+        (``fl.client.pad_clients`` of those devices, padded to the
+        stack's ``n_max``) becomes rows ``rows`` of ``clients``.  One
+        jitted write donates the stack, so the rows land in its
+        buffers; a placed (committed) stack keeps its sharding, an
+        unplaced one stays unplaced.  The writes go in fixed blocks of
+        ``ROW_BLOCK`` rows, so the write compiles once; the last block
+        repeats its last row, with that row's own data."""
+        if self._row_write is None:
+            placed = all(a.committed
+                         for a in jax.tree_util.tree_leaves(clients))
+            pin = dict(out_shardings=jax.tree_util.tree_map(
+                lambda a: a.sharding, clients)) if placed else {}
+            self._row_write = jax.jit(set_client_rows, donate_argnums=0,
+                                      **pin)
+        rows = np.asarray(rows, np.int32)
+        for s in range(0, len(rows), ROW_BLOCK):
+            take = np.minimum(np.arange(s, s + ROW_BLOCK), len(rows) - 1)
+            clients = self._row_write(
+                clients, rows[take],
+                jax.tree_util.tree_map(lambda a: a[take], block))
+        return clients
+
     # -------------------------------------------------- backend hooks
     def _train(self, params, clients, key, active, train_mask=None):
         raise NotImplementedError
@@ -245,6 +276,19 @@ class DevicePool:
     def _targeted_values_fn(self):
         """Row-targeted variant of ``_values_fn`` (budgeted refreshes)."""
         raise NotImplementedError
+
+    def _width(self, axis: str, n: int, bucket) -> int:
+        """Width of the row-targeted refresh's ``axis`` ("rows" gathered
+        or pair "lanes") for ``n``: the smallest width it already ran
+        that holds ``n``, else ``bucket(n)``.  A refresh below the
+        widths seen so far, such as the last few dirty pairs of a drift,
+        then reuses their programs instead of compiling its own."""
+        seen = self._widths.setdefault(axis, set())
+        w = min((s for s in seen if s >= n), default=None)
+        if w is None:
+            w = bucket(n)
+            seen.add(w)
+        return w
 
     # shared async merge: measurements refresh ONLY where a device ticked
     def _merge_measured(self, g, eps_g, acc_g, eps_prev, acc_prev):
@@ -322,13 +366,14 @@ class LocalPool(DevicePool):
     def _targeted_values_fn(self):
         """Single-host row targeting: one bucketed row gather for the
         whole pair batch (the compact clients replace the full (P,
-        n_max, ...) stack inside the vmapped pair kernel), pair lanes
-        padded to a power-of-two width so compilations stay bounded as
-        the dirty count wanders under the budget."""
+        n_max, ...) stack inside the vmapped pair kernel), rows and pair
+        lanes padded to a width already run (``_width``) or a power of
+        two, so compilations stay bounded as the dirty count wanders
+        under the budget."""
         def values(h0, clients, pi, pj, keys, *, tau, T, batch, lr):
             sub, ri, rj = _gather_pair_rows(
-                clients, pi, pj,
-                lambda r: _bucket(r, clients.n_devices))
+                clients, pi, pj, lambda r: self._width(
+                    "rows", r, lambda r: _bucket(r, clients.n_devices)))
 
             def call(ci, cj, ck):
                 return pairwise_divergence_values(
@@ -336,9 +381,10 @@ class LocalPool(DevicePool):
                     jnp.asarray(cj, jnp.int32), ck,
                     tau=tau, T=T, batch=batch, lr=lr)
 
-            return chunked_pair_lanes(ri, rj, keys,
-                                      _bucket(len(ri), PAIR_CHUNK),
-                                      call, pad_partial=True)
+            return chunked_pair_lanes(
+                ri, rj, keys, self._width(
+                    "lanes", len(ri), lambda n: _bucket(n, PAIR_CHUNK)),
+                call, pad_partial=True)
         return values
 
 
@@ -465,14 +511,18 @@ class ShardedPool(DevicePool):
         ALL-GATHERED inside ``build_pair_values`` — the cross-shard
         gather shrinks from the whole padded pool to just the rows this
         refresh touches, which is the row-targeted-gather headroom noted
-        when the sharding PR closed."""
+        when the sharding PR closed.  Rows and lanes take widths as the
+        single-host path does (``_width``)."""
         def values(h0, clients, pi, pj, keys, *, tau, T, batch, lr):
             del tau, T, batch, lr           # baked into _pair_fn at init
             sub, ri, rj = _gather_pair_rows(
-                clients, pi, pj,
-                lambda r: -(-_bucket(r, clients.n_devices)
-                            // self.n_shards) * self.n_shards)
-            w = min(PAIR_CHUNK, -(-len(ri) // self.n_shards))
+                clients, pi, pj, lambda r: self._width(
+                    "rows", r, lambda r: -(-_bucket(r, clients.n_devices)
+                                           // self.n_shards)
+                    * self.n_shards))
+            lanes = self._width("lanes", len(ri), lambda n: _bucket(
+                n, PAIR_CHUNK * self.n_shards))
+            w = min(PAIR_CHUNK, -(-lanes // self.n_shards))
 
             def call(ci, cj, ck):
                 return self._pair_fn(h0, sub, jnp.asarray(ci, jnp.int32),

@@ -12,8 +12,9 @@ The phases of a tick are top-level and disjoint, in the order they run:
 
   ``scenario``        the scenario's mutation (drift blends, first-drift
                       renders, churn, label reveals)
-  ``restack``         re-stacking the pool's client data and placing it
-                      on the device pool, after data changed
+  ``restack``         writing changed devices' rows into the placed
+                      client stack (or re-stacking it), after data
+                      changed
   ``train``           local training through the pool
   ``divergence``      Algorithm-1 pair estimation through the pool
                       (bootstrap, gossip meetings, budgeted refresh)

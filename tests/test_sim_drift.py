@@ -78,7 +78,7 @@ def test_drift_features_caches_dirties_and_is_absolute():
     assert st.div_dirty[2, :].sum() == st.pool_size - 1   # row dirtied
     assert st.div_dirty[:, 2].sum() == st.pool_size - 1
     assert not st.div_dirty[2, 2]
-    assert eng._restack
+    assert eng._dirty_clients == {2}             # its stack row is due
     drifted = st.pool[2].images.copy()
     assert not np.array_equal(drifted, base)
     # absolute mix: re-blending at the same mix reproduces, not compounds
@@ -147,6 +147,31 @@ def test_targeted_refresh_matches_full_path(mesh):
     merged = eng.pool.refresh_divergences(old, eng.state.clients, key,
                                           pairs, ema=1.0)
     np.testing.assert_allclose(merged, old)      # ema=1 keeps old values
+
+
+@pytest.mark.parametrize("mesh", [0, 1])
+def test_refresh_below_the_widths_run_compiles_nothing(mesh, compiles):
+    """The last few dirty pairs of a drift reuse the programs of the
+    refreshes before them: their keys, rows and lanes take widths
+    already run, and each pair's value is the one it had there."""
+    eng = SimulationEngine(SimConfig(scenario="static", devices=8,
+                                     rounds=1, mesh=mesh, **TINY))
+    ex, st = eng.executor, eng.state
+
+    def refresh(pairs):
+        return eng.pool.refresh_divergences(
+            np.zeros((8, 8)), st.clients, None, pairs,
+            keys=ex._pair_content_keys(pairs), h0=ex._refresh_h0())
+
+    ii, jj = np.triu_indices(8, k=1)
+    pairs = np.stack([ii, jj], axis=1).astype(np.int32)[:20]
+    full = refresh(pairs)
+    few = pairs[[3, 11, 17]]
+    with compiles() as compiled:
+        out = refresh(few)
+    assert compiled.n == 0
+    np.testing.assert_array_equal(out[few[:, 0], few[:, 1]],
+                                  full[few[:, 0], few[:, 1]])
 
 
 # ------------------------------------------- scenario registry round-trip

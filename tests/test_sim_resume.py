@@ -139,6 +139,34 @@ def test_network_state_checkpoint_roundtrip(tmp_path):
     assert eng2._resume_count == 1
 
 
+def test_drifted_device_restored_then_drifts_again(tmp_path):
+    """A device that drifted before the checkpoint comes back in the
+    restored stack, drifts again, and its rows are written into that
+    stack: after every resumed tick it is the pool's stack."""
+    import jax
+    from repro.fl.client import stack_clients
+    kw = dict(scenario="feature-drift", devices=6, seed=4,
+              feature_drift_p=0.8, ckpt_dir=str(tmp_path), **SMOKE)
+    SimulationEngine(SimConfig(rounds=2, checkpoint_every=1, **kw)).run()
+    eng = SimulationEngine(SimConfig(rounds=5, resume=True, **kw))
+    drifted = set(eng._drift_base)
+    assert drifted and not eng._dirty_clients
+    again = set()
+    for t in range(eng.state.round, 5):
+        row = eng.step(t)
+        eng.state.round = t + 1
+        touched = {e["device"] for e in row["events"]}
+        assert row["restack_rows"] == len(touched)
+        again |= touched & drifted
+        want = stack_clients(eng.state.pool)
+        for got, ref in zip(jax.tree_util.tree_leaves(eng.state.clients),
+                            jax.tree_util.tree_leaves(want)):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(ref))
+    eng.logger.close()
+    assert again, "no device drifted on both sides of the resume"
+
+
 def test_resume_cfg_mismatch_raises(tmp_path):
     cfg = SimConfig(scenario="static", devices=6, rounds=1, seed=0,
                     ckpt_dir=str(tmp_path), checkpoint_every=1, **SMOKE)

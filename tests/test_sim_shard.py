@@ -44,6 +44,11 @@ ASYNC_GOLDEN = dict(scenario="async-gossip", engine="async-gossip",
                     resolve_patience=3, **SMOKE)
 STATIC_GOLDEN = dict(scenario="static", devices=8, rounds=3, seed=0,
                      reseed_on_rejoin=False, **SMOKE)
+#: feature drift over a pool of 8 with drifts in every tick
+DRIFT_RESTACK = dict(scenario="feature-drift", devices=8, rounds=3, seed=1,
+                     feature_drift_p=0.9, samples_per_device=8,
+                     train_iters=2, div_tau=1, div_T=2, batch=4,
+                     solver_max_outer=2, solver_inner_steps=120)
 
 
 def _golden(name):
@@ -291,7 +296,8 @@ def test_mesh8_emulated_reproduces_goldens():
     """Satellite acceptance: an emulated 8-shard mesh (8 host-platform
     devices forced BEFORE jax import, hence the subprocess) must
     reproduce the single-host goldens field-for-field for both the
-    static (sync) and async-gossip scenarios."""
+    static (sync) and async-gossip scenarios, and keep the drifted
+    client stack equal to the pool's in its sharding."""
     script = textwrap.dedent(f"""
         import os
         os.environ["XLA_FLAGS"] = (
@@ -321,6 +327,26 @@ def test_mesh8_emulated_reproduces_goldens():
               {STATIC_GOLDEN!r}, "mesh8-static")
         check({os.path.join(GOLDEN_DIR, "sim_async-gossip.jsonl")!r},
               {ASYNC_GOLDEN!r}, "mesh8-async")
+
+        # drift writes changed rows into the 8-way sharded client stack:
+        # after every tick it is the pool's stack, in its placed sharding
+        import jax
+        from repro.fl.client import stack_clients
+        eng = SimulationEngine(SimConfig(mesh=8, **{DRIFT_RESTACK!r}))
+        shardings = lambda c: jax.tree_util.tree_map(  # noqa: E731
+            lambda a: a.sharding, c)
+        placed = shardings(eng.state.clients)
+        written = 0
+        for t in range(eng.cfg.rounds):
+            written += eng.step(t)["restack_rows"]
+            eng.state.round = t + 1
+            want = stack_clients(eng.state.pool)
+            for a, b in zip(jax.tree_util.tree_leaves(eng.state.clients),
+                            jax.tree_util.tree_leaves(want)):
+                assert np.array_equal(np.asarray(a), np.asarray(b)), t
+            assert shardings(eng.state.clients) == placed, t
+        assert written > 0
+        print("mesh8-drift-restack OK", flush=True)
     """)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
@@ -331,3 +357,4 @@ def test_mesh8_emulated_reproduces_goldens():
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "mesh8-static OK" in proc.stdout
     assert "mesh8-async OK" in proc.stdout
+    assert "mesh8-drift-restack OK" in proc.stdout

@@ -69,20 +69,21 @@ def _sim_spans(trace_dir):
 
 def _profiled_run(engine, scenario, trace_dir):
     """ROUNDS traced ticks at N=8 under the profiler: the recorder's
-    events, the rows, each step's (start, end) ns, the bytes handed to
-    ``place_clients`` per tick, and the profile's ``sim.`` spans."""
+    events, the rows, each step's (start, end) ns, the rows and bytes
+    handed to ``write_client_rows`` per tick, and the profile's ``sim.``
+    spans."""
     eng = SimulationEngine(SimConfig(
         scenario=scenario, engine=engine, devices=8, rounds=ROUNDS,
         seed=SEED, trace=True, verbose=False, **SMOKE))
-    placed = {}
-    place = eng.pool.place_clients
+    written = {}
+    write = eng.pool.write_client_rows
 
-    def spy(clients):
-        placed[eng.trace.tick] = sum(
-            leaf.nbytes for leaf in jax.tree_util.tree_leaves(clients))
-        return place(clients)
+    def spy(clients, rows, block):
+        written[eng.trace.tick] = (len(rows), sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(block)))
+        return write(clients, rows, block)
 
-    eng.pool.place_clients = spy
+    eng.pool.write_client_rows = spy
     rows, steps = [], {}
     jax.profiler.start_trace(str(trace_dir))
     try:
@@ -96,7 +97,7 @@ def _profiled_run(engine, scenario, trace_dir):
     eng.trace.close()
     return types.SimpleNamespace(
         events=sorted(eng.trace.events, key=lambda e: e["t0_ns"]),
-        rows=rows, steps=steps, placed=placed,
+        rows=rows, steps=steps, written=written,
         spans=_sim_spans(trace_dir), rendered=len(eng._drift_alt))
 
 
@@ -250,16 +251,21 @@ def test_tracing_off_opens_no_span_and_registers_no_listener(tmp_path):
 # ------------------------------------------------------------ counters
 @pytest.mark.parametrize("engine", ENGINES)
 def test_restack_bytes_is_the_placed_stack(profiled, engine):
+    """The restack writes only the drifted devices' rows into the
+    placed stack, and ``restack_bytes`` counts those rows' bytes."""
     run = profiled(engine, "feature-drift")
-    got = {r["round"]: r["restack_bytes"] for r in run.rows}
-    assert got == {t: run.placed.get(t, 0) for t in got}
-    assert any(got.values()) and not all(got.values())
+    got = {r["round"]: (r["restack_rows"], r["restack_bytes"])
+           for r in run.rows}
+    assert got == {t: run.written.get(t, (0, 0)) for t in got}
+    assert any(b for _, b in got.values())
+    assert not all(b for _, b in got.values())
+    # a row's size follows from its shapes: 8 samples of 28x28x3
+    # float32, four int32/bool entries a sample, and one int32 count
+    row_bytes = 8 * (28 * 28 * 3 * 4 + 4 + 1 + 1 + 4) + 4
     for row in run.rows:
         assert bool(row["restack_bytes"]) == (row["n_drifted"] > 0)
-    # the stack's size follows from its shapes: 8 devices x 8 samples
-    # of 28x28x3 float32, four (8, 8) int32/bool arrays, 8 counts
-    assert max(got.values()) == 8 * 8 * (28 * 28 * 3 * 4 + 4 + 1 + 1
-                                         + 4) + 8 * 4
+        assert row["restack_rows"] == row["n_drifted"]
+        assert row["restack_bytes"] == row["restack_rows"] * row_bytes
 
 
 @pytest.mark.parametrize("engine", ENGINES)
