@@ -7,7 +7,8 @@ round, the inputs that the plain reference needs to follow the same
 rounds from the seed (the devices' data, the active set, the pairs
 measured, the solved assignment the transfer used) and the
 answers the program gave (accuracies, and at the end the parameter
-stack and the divergence matrix).  The wrappers come off before the
+stack, flat by path as ``replay.flat_leaves`` keys it, and the
+divergence matrix).  The wrappers come off before the
 window starts.
 """
 from __future__ import annotations
@@ -15,6 +16,8 @@ from __future__ import annotations
 from typing import List
 
 import numpy as np
+
+from harness.replay import flat_leaves
 
 POOL_CALLS = ("train", "update_divergences", "refresh_divergences",
               "transfer", "accuracies")
@@ -68,5 +71,5 @@ class Capture:
         for name in POOL_CALLS:
             del pool.__dict__[name]
         st = self.engine.state
-        return {"params": {k: np.asarray(v) for k, v in st.params.items()},
+        return {"params": flat_leaves(st.params),
                 "div_hat": np.array(st.div_hat)}

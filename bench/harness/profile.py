@@ -9,7 +9,9 @@ trace; ``load`` turns an ``.xplane.pb`` into them:
                HLO instruction's (``alpha_combine_flat.1``, ``while.13``)
   host spans   (start_ns, duration_ns, name) of the annotations the
                harness writes around each round and each call into a
-               layer (``jax.profiler.TraceAnnotation``)
+               layer, and of the program's phase spans (``sim.<phase>``,
+               one per TraceRecorder event); both are
+               ``jax.profiler.TraceAnnotation``s
 """
 from __future__ import annotations
 
@@ -25,6 +27,8 @@ OPS_LINE = "XLA Ops"
 #: the annotation the harness puts around the whole window
 WINDOW_SPAN = "bench.window"
 ROUND_SPAN = "bench.round"
+#: host spans kept: the harness's own and the program's phases
+SPAN_PREFIXES = ("bench.", "sim.")
 
 
 def union(intervals: Sequence[Tuple[float, float]]) -> List[Tuple[float,
@@ -73,8 +77,9 @@ def op_totals(events: Sequence[Event]) -> Dict[str, float]:
 
 
 def label_gap(gap: Tuple[float, float], spans: Sequence[Event]) -> str:
-    """The innermost host span that covers the gap's midpoint, with the
-    round it lies in; "host" where no span covers it."""
+    """The innermost host span, the harness's or the program's, that
+    covers the gap's midpoint, with the round it lies in; "host" where
+    no span covers it."""
     mid = (gap[0] + gap[1]) / 2
     covering = [(d, name) for s, d, name in spans if s <= mid <= s + d]
     if not covering:
@@ -134,8 +139,8 @@ def op_name(text: str) -> str:
 
 
 def load(trace_dir: str) -> Tuple[List[List[Event]], List[Event]]:
-    """Device ops per chip and the harness's host spans from the newest
-    ``.xplane.pb`` under ``trace_dir``."""
+    """Device ops per chip, and the harness's and the program's host
+    spans, from the newest ``.xplane.pb`` under ``trace_dir``."""
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True), key=os.path.getmtime)
@@ -154,7 +159,7 @@ def load(trace_dir: str) -> Tuple[List[List[Event]], List[Event]]:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith("bench."):
+                    if ev.name.startswith(SPAN_PREFIXES):
                         spans.append((ev.start_ns, ev.duration_ns,
                                       ev.name))
     return [chips[k] for k in sorted(chips)], spans

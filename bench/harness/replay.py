@@ -1,15 +1,18 @@
 """The correctness check: the plain reference follows the warm-up rounds
 from the seed, and its answers are compared with the program's.
 
-The reference starts from its own weights, drawn from the seed with the
-simulator's key schedule, and follows each warm-up round as the sync
-executor defines it: every labeled active device trains, then targets
-take the alpha-mixture of the assignment the round installed.  It
-measures the accuracy of every device after each round.  Pair
-divergences (Algorithm 1) are followed for a sample of the measured
-pairs, drawn from the seed, through every measurement each had in the
-warm-up (the bootstrap, the drift refresh), with the simulator's merge
-rule.
+The reference is the client's reference file, reached only through the
+contract in ``spec.py``: its ``init`` gives the per-device parameters,
+its ``stack`` the device-major data layout, and the class count is the
+configuration's ``model.classes``.  It starts from its own weights,
+drawn from the seed with the simulator's key schedule, and follows each
+warm-up round as the sync executor defines it: every labeled active
+device trains, then targets take the alpha-mixture of the assignment
+the round installed.  It measures the accuracy of every device after
+each round.  Pair divergences (Algorithm 1) are followed for a sample of
+the measured pairs, drawn from the seed, through every measurement each
+had in the warm-up (the bootstrap, the drift refresh), with the
+simulator's merge rule.
 
 What the reference takes from the run is its input and its control
 decisions: each device's data (the traffic), which devices were active,
@@ -19,7 +22,8 @@ the constraints of program (P) instead (``solve_faults``).
 
 Numbers read (each a worst case over the warm-up; the cell's limits
 file says which are compared):
-  param_gap     per leaf of the device stack, the gap between the
+  param_gap     per leaf of the device stack (keyed by its path in the
+                parameter tree, ``flat_leaves``), the gap between the
                 norms of the parameter change over the warm-up in the
                 program and in the reference, over the larger of the
                 reference's change of that leaf and of the median leaf;
@@ -50,27 +54,17 @@ CONTENT_INIT_STREAM = 2 ** 21
 PAIR_KEY_CHUNK = 256
 
 
-def stack(devices, dtype) -> dict:
-    """Device-major arrays of the devices' data, padded to the largest."""
-    n_max = max(len(d.labels) for d in devices)
+#: Algorithm 1's classifier tells device i's rows from device j's
+DOMAIN_CLASSES = 2
 
-    def pad(arrs, fill, dt):
-        out = np.full((len(arrs), n_max) + arrs[0].shape[1:], fill, dt)
-        for i, a in enumerate(arrs):
-            out[i, :len(a)] = a
-        return out
 
-    return {
-        "x": jnp.asarray(pad([d.images for d in devices], 0.0,
-                             np.float32), dtype),
-        "y": jnp.asarray(pad([d.labels for d in devices], -1, np.int32)),
-        "labeled": jnp.asarray(pad([d.labeled_mask for d in devices],
-                                   False, bool)),
-        "valid": jnp.asarray(pad([np.ones(len(d.labels), bool)
-                                  for d in devices], False, bool)),
-        "true_y": jnp.asarray(pad([d.true_labels for d in devices], -1,
-                                  np.int32)),
-    }
+def flat_leaves(tree) -> Dict[str, np.ndarray]:
+    """The leaves of a parameter tree as numpy arrays, keyed by their
+    path: a flat dict keeps its names (``conv1``), a nested one joins
+    them (``adapter/down``)."""
+    return {jax.tree_util.keystr(path, simple=True, separator="/"):
+            np.asarray(leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
 def _root_keys(seed: int):
@@ -133,25 +127,28 @@ def _measurements(ticks, pairs, div_ema: float, key_mode: str):
 
 
 class Follower:
-    """The reference's run of the warm-up rounds, in ``dtype``."""
+    """The reference's run of the warm-up rounds, in ``dtype``, of a
+    client with ``classes`` classes."""
 
-    def __init__(self, ref, cell_sim: dict, seed: int, dtype):
+    def __init__(self, ref, cell_sim: dict, seed: int, dtype, classes: int):
         self.ref = ref
         self.sim = cell_sim
         self.seed = seed
         self.dtype = dtype
+        self.classes = classes
 
     # ------------------------------------------------------ devices
-    def devices(self, ticks) -> Tuple[dict, List[np.ndarray]]:
-        """Parameters after the last warm-up round, and every device's
-        accuracy after each round."""
+    def devices(self, ticks) -> Tuple[dict, List[np.ndarray], dict]:
+        """Parameters after the last warm-up round, every device's
+        accuracy after each round, and the parameters at the start; the
+        parameters flat by path (``flat_leaves``)."""
         sim, ref, dt = self.sim, self.ref, self.dtype
         k0, run_key = _root_keys(self.seed)
         k_init = jax.random.split(k0)[0]
         n = len(ticks[0]["data"])
-        p0 = ref.init(k_init, 10)
-        params = ref.cast({k: jnp.broadcast_to(v, (n,) + v.shape)
-                           for k, v in p0.items()}, dt)
+        p0 = ref.init(k_init, self.classes)
+        params = ref.cast(jax.tree_util.tree_map(
+            lambda v: jnp.broadcast_to(v, (n,) + v.shape), p0), dt)
         accs = []
         data, data_src = None, None
         for tick in ticks:
@@ -159,7 +156,7 @@ class Follower:
             if data_src is None or any(
                     a is not b for a, b in zip(data_src, tick["data"])):
                 data_src = tick["data"]
-                data = stack(data_src, dt)
+                data = ref.stack(data_src, dt)
             update = np.asarray(data["labeled"]).any(axis=1) & \
                 tick["active"]
             lane_keys = jax.random.split(jax.random.fold_in(run_key, t), n)
@@ -172,10 +169,10 @@ class Follower:
             accs.append(np.asarray(ref.accuracies(
                 params, data["x"], data["true_y"], data["valid"],
                 dtype=dt), float))
-        init = {k: np.broadcast_to(np.asarray(v), (n,) + v.shape)
-                for k, v in p0.items()}
-        return ({k: np.asarray(v, np.float32) for k, v in params.items()},
-                accs, init)
+        init = {k: np.broadcast_to(v, (n,) + v.shape)
+                for k, v in flat_leaves(p0).items()}
+        return ({k: np.asarray(v, np.float32)
+                 for k, v in flat_leaves(params).items()}, accs, init)
 
     # --------------------------------------------------------- pairs
     def pairs(self, ticks, pairs) -> Dict[Tuple[int, int], float]:
@@ -187,14 +184,14 @@ class Follower:
             return {}
         k0, run_key = _root_keys(self.seed)
         content_h0 = ref.init(jax.random.fold_in(k0, CONTENT_INIT_STREAM),
-                              2)
+                              DOMAIN_CLASSES)
         content_base = jax.random.fold_in(k0, CONTENT_KEY_STREAM)
         by_tick = {tick["tick"]: tick for tick in ticks}
-        xs_i, xs_j, ns_i, ns_j, keys, h0s = [], [], [], [], [], []
+        devs_i, devs_j, ns_i, ns_j, keys, h0s = [], [], [], [], [], []
         for (a, b), t, mode, pos, size, _ in meas:
             data = by_tick[t]["data"]
-            xs_i.append(data[a].images)
-            xs_j.append(data[b].images)
+            devs_i.append(data[a])
+            devs_j.append(data[b])
             ns_i.append(len(data[a].labels))
             ns_j.append(len(data[b].labels))
             if mode == "content":
@@ -206,24 +203,20 @@ class Follower:
                                            1)
                 key, init_key = jax.random.split(k_div)
                 keys.append(_positional_key(key, size, pos))
-                h0s.append(ref.init(init_key, 2))
-        n_max = max(len(x) for x in xs_i + xs_j)
+                h0s.append(ref.init(init_key, DOMAIN_CLASSES))
+        # both sides padded to one length: the reference's draws over
+        # padded rows and its compiled widths depend on it
+        n_max = max(ns_i + ns_j)
         # lanes padded to a power of two by repeating the first, so the
         # reference compiles for a few widths, not for every count
         lanes = max(64, 1 << (len(meas) - 1).bit_length())
-        for lst in (xs_i, xs_j, ns_i, ns_j, keys, h0s):
+        for lst in (devs_i, devs_j, ns_i, ns_j, keys, h0s):
             lst.extend(lst[:1] * (lanes - len(meas)))
-
-        def pad(xs):
-            out = np.zeros((len(xs), n_max) + xs[0].shape[1:], np.float32)
-            for k, x in enumerate(xs):
-                out[k, :len(x)] = x
-            return jnp.asarray(out, dt)
-
         h0 = ref.cast(jax.tree_util.tree_map(lambda *v: jnp.stack(v),
                                              *h0s), dt)
         vals = np.asarray(ref.pair_divergence(
-            h0, pad(xs_i), jnp.asarray(ns_i), pad(xs_j), jnp.asarray(ns_j),
+            h0, ref.stack(devs_i, dt, n_max)["x"], jnp.asarray(ns_i),
+            ref.stack(devs_j, dt, n_max)["x"], jnp.asarray(ns_j),
             jnp.stack(keys), tau=sim["div_tau"], T=sim["div_T"],
             batch=sim["batch"], lr=sim["lr"], dtype=dt), float)[:len(meas)]
         est: Dict[Tuple[int, int], float] = {}
@@ -238,7 +231,9 @@ def param_gap(prog: dict, ref: dict, init: dict) -> float:
     program's and the reference's parameter change over the warm-up (not
     the norm of their difference: the directions part under rounding),
     over the larger of the reference's norm of that leaf and of the
-    median leaf; the worst leaf."""
+    median leaf; the worst leaf.  Leaves are matched by their path."""
+    prog, ref, init = flat_leaves(prog), flat_leaves(ref), flat_leaves(init)
+
     def change(params, name):
         return float(np.linalg.norm(np.asarray(params[name], np.float64)
                                     - np.asarray(init[name], np.float64)))

@@ -49,13 +49,15 @@ SPANS = (("pool.train", "train"),
 class Window:
     """What a per-layer metric reader gets: the window's rounds, the
     program's phase events in it, the device trace's summary, the run's
-    simulator settings and the chip's peaks."""
+    simulator settings, the chip's peaks and the client's costs
+    (``costs(sim)`` of the configuration's reference file)."""
     rounds: List[dict]              # {"tick", "wall", "row"}
     seconds: float
     phases: Dict[int, Dict[str, float]]
     profile: Optional[proflib.Summary]
     sim: dict
     peaks: Optional[dict]
+    costs: Optional[dict] = None
 
     @property
     def ticks(self) -> List[int]:
@@ -66,8 +68,7 @@ class Window:
                    for t in self.ticks for n in names)
 
     def model_flops(self) -> float:
-        samples = self.sim["samples_per_device"]
-        return sum(flopslib.round_flops(r["row"], self.sim, samples)
+        return sum(flopslib.round_flops(r["row"], self.sim, self.costs)
                    for r in self.rounds)
 
 
@@ -236,7 +237,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
         win = Window(rounds=rounds, seconds=window_s,
                      phases=_phases(engine.trace.events,
                                     [r["tick"] for r in rounds]),
-                     profile=None, sim=sim, peaks=peaks)
+                     profile=None, sim=sim, peaks=peaks,
+                     costs=reference_module(cell).costs(sim))
         failed = sum(not _finite_row(r["row"]) for r in rounds)
         engine.logger.close()
         cap.engine = None
@@ -361,11 +363,13 @@ def check(cell: Cell, seed: int, sim: dict, ticks, end: dict,
     ref = reference_module(cell)
     pairs = replay.sample_pairs(ticks, seed,
                                 int(cell.traffic["check_pairs_per_call"]))
-    ref_side = replay.follow(replay.Follower(ref, sim, seed, jnp.float32),
-                             ticks, pairs)
+    classes = int(cell.config["model"]["classes"])
+    ref_side = replay.follow(
+        replay.Follower(ref, sim, seed, jnp.float32, classes), ticks, pairs)
     if control:
-        side = replay.follow(replay.Follower(ref, sim, seed, jnp.bfloat16),
-                             ticks, pairs)
+        side = replay.follow(
+            replay.Follower(ref, sim, seed, jnp.bfloat16, classes), ticks,
+            pairs)
     else:
         side = replay.program_side(ticks, end, pairs)
     got = replay.compare(side, ref_side, ticks)

@@ -11,6 +11,41 @@ from files of their own.
 
 A later cell or metric is a new file plus a new entry in
 ``BENCHMARK.json``; no file the harness reads needs an edit.
+
+The client model is the reference's alone.  The configuration names it
+(``"reference": "<name>"``) and gives its class count
+(``model.classes``); the harness calls only these functions of
+``bench/reference/<name>.py``:
+
+  init(key, num_classes) -> tree
+      the per-device parameters, from ``key``.  Anything every device
+      shares (a frozen backbone) stays inside the module, derived from
+      the key it is given; the harness broadcasts to the devices, and
+      compares, only what ``init`` returns.  Leaves are keyed by their
+      path in the tree (``replay.flat_leaves``), so the tree's names
+      must match the program's parameters.
+  cast(tree, dtype) -> tree
+  stack(devices, dtype, n_max=None) -> dict
+      the client's device-major data layout, from the device objects
+      the run captured, padded to ``n_max`` rows (default: the largest
+      device's): ``x``, ``y``, ``labeled``, ``valid``, ``true_y``.
+      Only floating-point inputs take ``dtype``; integer inputs (token
+      ids) stay integers.
+  train(params, x, y, labeled, valid, lane_keys, update, *, iters,
+        batch, lr, dtype) -> params
+  accuracies(params, x, true_y, valid, *, dtype) -> (devices,)
+  pair_divergence(h0, xi, ni, xj, nj, keys, *, tau, T, batch, lr,
+                  dtype) -> (lanes,)
+  mix_targets(params, alpha, psi, dtype) -> params
+  costs(sim) -> dict
+      operations of ``train_sample`` (one training sample),
+      ``eval_sample`` (one measurement forward) and ``pair`` (one
+      Algorithm-1 estimate, training and evaluation), and
+      ``transfer_width``: V, the per-device parameters the combine
+      moves; ``sim`` is the run's ``SimConfig`` as a dict.
+
+A new client is new files only: a config, a reference, traffic, limits
+and metric readers.
 """
 from __future__ import annotations
 

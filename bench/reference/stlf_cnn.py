@@ -11,6 +11,12 @@ arithmetic in bfloat16: the control, the precision below the float32 the
 configuration states.  Random draws (sample indices, initial weights)
 come from ``jax.random`` with the simulator's key schedule, so the
 reference sees the inputs the simulator saw.
+
+This file is the client's side of the harness's contract
+(``bench/harness/spec.py``): ``init``, ``cast``, ``stack``, ``train``,
+``accuracies``, ``pair_divergence``, ``mix_targets`` and ``costs``.  The
+CNN has no shared part: ``init`` returns the whole model, flat by leaf
+name, and ``stack`` the device-major images.
 """
 from __future__ import annotations
 
@@ -23,6 +29,13 @@ import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
 DN = ("NHWC", "HWIO", "NHWC")
+#: the client's classes (the configuration's ``model.classes``) and
+#: Algorithm 1's domain classifier, which tells device i's rows from j's
+NUM_CLASSES = 10
+DOMAIN_CLASSES = 2
+#: a training sample costs three forwards: the forward, and a backward of
+#: twice the work
+TRAIN_FACTOR = 3
 
 
 def shapes(num_classes: int, in_ch: int = 3) -> dict:
@@ -49,6 +62,32 @@ def init(key, num_classes: int) -> dict:
             out[name] = jax.random.normal(k, s, jnp.float32) / \
                 math.sqrt(math.prod(s[:-1]))
     return out
+
+
+def stack(devices, dtype, n_max=None) -> dict:
+    """Device-major arrays of the devices' data, padded to ``n_max`` rows
+    (default: the largest device's); the images take ``dtype``, labels
+    and masks keep their integer and boolean types."""
+    if n_max is None:
+        n_max = max(len(d.labels) for d in devices)
+
+    def pad(arrs, fill, dt):
+        out = np.full((len(arrs), n_max) + arrs[0].shape[1:], fill, dt)
+        for i, a in enumerate(arrs):
+            out[i, :len(a)] = a
+        return out
+
+    return {
+        "x": jnp.asarray(pad([d.images for d in devices], 0.0,
+                             np.float32), dtype),
+        "y": jnp.asarray(pad([d.labels for d in devices], -1, np.int32)),
+        "labeled": jnp.asarray(pad([d.labeled_mask for d in devices],
+                                   False, bool)),
+        "valid": jnp.asarray(pad([np.ones(len(d.labels), bool)
+                                  for d in devices], False, bool)),
+        "true_y": jnp.asarray(pad([d.true_labels for d in devices], -1,
+                                  np.int32)),
+    }
 
 
 def _prec(dtype):
@@ -197,3 +236,54 @@ def mix_targets(params: dict, alpha: np.ndarray, psi: np.ndarray,
                                   th).reshape(leaf.shape)
     return out
 
+
+
+# --------------------------------------------------------------- costs
+# Multiply-adds of one forward sample on 28x28x3 inputs, by layer:
+#
+#   conv1  24*24 outputs * 10 maps * 5*5*3      = 432,000
+#   conv2   8*8  outputs * 20 maps * 5*5*10     = 320,000
+#   fc1    320 * 128                            =  40,960
+#   fc2    128 * classes                        =   1,280 (10 classes)
+#
+# 794,240 multiply-adds (1,588,480 operations) with 10 classes.  Biases,
+# activations and pooling are not counted.
+def cnn_forward_macs(num_classes: int = NUM_CLASSES, hw: int = 28,
+                     in_ch: int = 3, k: int = 5, maps=(10, 20),
+                     hidden: int = 128) -> int:
+    o1 = hw - k + 1                         # 24
+    p1 = o1 // 2                            # 12
+    o2 = p1 - k + 1                         # 8
+    p2 = o2 // 2                            # 4
+    conv1 = o1 * o1 * maps[0] * k * k * in_ch
+    conv2 = o2 * o2 * maps[1] * k * k * maps[0]
+    flat = p2 * p2 * maps[1]
+    return conv1 + conv2 + flat * hidden + hidden * num_classes
+
+
+def cnn_forward_flops(num_classes: int = NUM_CLASSES) -> int:
+    return 2 * cnn_forward_macs(num_classes)
+
+
+def cnn_params(num_classes: int = NUM_CLASSES) -> int:
+    return (5 * 5 * 3 * 10 + 10) + (5 * 5 * 10 * 20 + 20) + \
+        (320 * 128 + 128) + (128 * num_classes + num_classes)
+
+
+def costs(sim: dict) -> dict:
+    """Operations of the client's work under the run's settings ``sim``:
+
+      train_sample    one training sample, three forwards
+      eval_sample     one measurement forward
+      pair            one Algorithm-1 estimate: 2 domain classifiers *
+                      tau * T steps * batch samples, three forwards
+                      each, then a forward of each device's samples
+      transfer_width  V, the parameters of a device the combine moves
+    """
+    f = cnn_forward_flops(NUM_CLASSES)
+    f2 = cnn_forward_flops(DOMAIN_CLASSES)
+    steps = 2 * sim["div_tau"] * sim["div_T"] * sim["batch"]
+    return {"train_sample": TRAIN_FACTOR * f, "eval_sample": f,
+            "pair": (steps * TRAIN_FACTOR
+                     + 2 * sim["samples_per_device"]) * f2,
+            "transfer_width": cnn_params(NUM_CLASSES)}

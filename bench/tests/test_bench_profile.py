@@ -17,6 +17,8 @@ def _trace():
              (0.0, 55 * MS, P.ROUND_SPAN + " 7"),
              (55 * MS, 45 * MS, P.ROUND_SPAN + " 8"),
              (40 * MS, 18 * MS, "bench.solve"),
+             (38 * MS, 21 * MS, "sim.solve"),
+             (0.0, 9 * MS, "sim.restack"),
              (72 * MS, 20 * MS, "bench.log")]
     return ops, spans
 
@@ -56,11 +58,16 @@ def test_kernel_time_and_count():
 
 
 def test_idle_gaps_are_named_by_the_host_span():
+    """By the innermost span, the harness's or the program's: the gap in
+    a restack the harness does not wrap is the program's ``sim.restack``,
+    the gap in a solve the harness's ``bench.solve`` inside ``sim.solve``."""
     ops, spans = _trace()
     b = P.breakdown(P.summarize([ops], spans))
     gaps = dict((name, sec) for name, sec in b["idle_gaps"])
+    assert set(gaps) == {"bench.log (round 8)", "sim.restack (round 7)",
+                         "bench.solve (round 7)"}
     assert gaps["bench.log (round 8)"] == pytest.approx(0.025)
-    assert gaps["host (round 7)"] == pytest.approx(0.010)
+    assert gaps["sim.restack (round 7)"] == pytest.approx(0.010)
     assert gaps["bench.solve (round 7)"] == pytest.approx(0.020)
     assert b["device_ops"][0] == ["fusion.1", pytest.approx(0.025)]
 
@@ -69,3 +76,20 @@ def test_a_trace_without_the_window_span_is_refused():
     ops, _ = _trace()
     with pytest.raises(ValueError):
         P.summarize([ops], [])
+
+
+def test_load_keeps_the_harness_and_program_spans(tmp_path):
+    """A trace recorded on the host: the harness's ``bench.*`` and the
+    program's ``sim.*`` annotations are kept, others are not."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for name in (P.WINDOW_SPAN, "sim.restack", "other.span"):
+            with jax.profiler.TraceAnnotation(name):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    _, spans = P.load(str(tmp_path))
+    names = {name for _, _, name in spans}
+    assert {P.WINDOW_SPAN, "sim.restack"} <= names
+    assert "other.span" not in names
