@@ -30,14 +30,18 @@ class SSMConfig:
     expand: int = 2              # d_inner = expand * d_model
     conv_width: int = 4
     chunk: int = 128             # chunked-scan block length
+    ngroups: int = 1             # B, C shared by nheads / ngroups heads
 
 
 @dataclasses.dataclass(frozen=True)
 class HybridConfig:
-    """Zamba2-style: mamba2 backbone + a shared attention block applied
-    every ``attn_every`` layers (weights shared across applications)."""
-    attn_every: int = 6
+    """Zamba2-style: a Mamba2 backbone whose ``layer_ids`` layers first
+    apply one of ``num_shared_blocks`` shared transformer blocks, in turn
+    (weights shared across applications), each invocation with its own
+    rank-``adapter_rank`` adapter on the shared MLP's gate_up."""
+    layer_ids: Tuple[int, ...] = ()
     num_shared_blocks: int = 2
+    adapter_rank: int = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,8 +136,11 @@ class ModelConfig:
             repl["ssm"] = dataclasses.replace(
                 self.ssm, state_dim=32, head_dim=32, chunk=32)
         if self.hybrid is not None:
+            ids = tuple(range(1, num_layers, 2))
             repl["hybrid"] = dataclasses.replace(
-                self.hybrid, attn_every=2, num_shared_blocks=1)
+                self.hybrid, layer_ids=ids,
+                num_shared_blocks=min(len(ids), self.hybrid.num_shared_blocks),
+                adapter_rank=8)
         if self.encdec is not None:
             repl["encdec"] = dataclasses.replace(
                 self.encdec, num_encoder_layers=num_layers, encoder_seq=32)

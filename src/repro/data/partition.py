@@ -17,11 +17,20 @@ from repro.data.digits import DigitDataset, make_domain_dataset, make_mixture
 
 @dataclasses.dataclass
 class DeviceData:
-    images: np.ndarray          # (n_i, 28, 28, 3)
-    labels: np.ndarray          # (n_i,) int32; -1 where unlabeled
+    """One device's samples: images (n_i, 28, 28, 3) float32 with one
+    label each, or token sequences (n_i, seq_len) int32 with one label
+    per token.  Labels are -1 where unlabeled; a sample is labeled or not
+    as a whole."""
+    x: np.ndarray               # (n_i, ...) the samples
+    labels: np.ndarray          # (n_i,) or (n_i, seq_len) int32
     labeled_mask: np.ndarray    # (n_i,) bool
     domain_ids: np.ndarray      # (n_i,) int32
-    true_labels: np.ndarray = None  # (n_i,) int32 — held out, eval only
+    true_labels: np.ndarray = None  # like labels — held out, eval only
+
+    @property
+    def images(self) -> np.ndarray:
+        """``x`` under the name image clients read it by."""
+        return self.x
 
     @property
     def n(self) -> int:
@@ -121,8 +130,9 @@ def reveal_labels(dev: DeviceData, frac: float,
         return dev
     mask = dev.labeled_mask.copy()
     mask[rng.choice(hidden, size=k, replace=False)] = True
-    shown = np.where(mask, dev.true_labels, -1).astype(np.int32)
-    return DeviceData(dev.images, shown, mask, dev.domain_ids,
+    shown = np.where(mask.reshape((-1,) + (1,) * (dev.true_labels.ndim - 1)),
+                     dev.true_labels, -1).astype(np.int32)
+    return DeviceData(dev.x, shown, mask, dev.domain_ids,
                       dev.true_labels)
 
 

@@ -52,23 +52,30 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.fl import cnn
-from repro.fl.client import StackedClients
+from repro.fl.client import CNNClient, PairInputs
 
 
-def _binary_loss(params, x, y):
-    return cnn.xent_loss(params, x, y)
+def _client(client):
+    return CNNClient() if client is None else client
 
 
-@functools.partial(jax.jit, static_argnames=("tau", "T", "batch", "lr"))
-def pairwise_divergence_values(h0, clients: StackedClients, pair_i, pair_j,
+@functools.partial(jax.jit, static_argnames=("tau", "T", "batch", "lr",
+                                             "client"))
+def pairwise_divergence_values(h0, clients: PairInputs, pair_i, pair_j,
                                keys, *, tau: int, T: int, batch: int,
-                               lr: float):
-    """h0: single init param tree (shared h').  pair_i/j: (P,) int32;
+                               lr: float, client=None):
+    """h0: single init of the client's 2-class hypothesis (shared h');
+    ``clients``: the rows it classifies (``PairInputs``: ``x`` and
+    ``counts``; a ``StackedClients`` serves the CNN).  pair_i/j: (P,) int32;
     ``keys``: per-pair PRNG keys, (P, key_dim) — see ``pair_keys``.  Each
     pair's estimate depends only on its own (i, j, key) lane, so callers
     are free to re-chunk or shard the pair axis (the mesh-sharded pool
-    does exactly that) without changing any value."""
+    does exactly that) without changing any value.  ``batch`` counts
+    samples: a step draws ``batch`` rows, or for a token client
+    ``batch * tokens_per_sample`` token rows, from each device."""
+    client = _client(client)
+    binary_loss = client.pair_loss
+    rows = batch * max(client.tokens_per_sample, 1)
     n_dev, n_max = clients.x.shape[0], clients.x.shape[1]
     flat_x = jnp.reshape(clients.x, (n_dev * n_max,) + clients.x.shape[2:])
 
@@ -80,12 +87,12 @@ def pairwise_divergence_values(h0, clients: StackedClients, pair_i, pair_j,
             hi, hj = carry
             t, kt = inputs
             ki, kj = jax.random.split(kt)
-            ridx_i = jax.random.randint(ki, (batch,), 0, clients.counts[i])
-            ridx_j = jax.random.randint(kj, (batch,), 0, clients.counts[j])
+            ridx_i = jax.random.randint(ki, (rows,), 0, clients.counts[i])
+            ridx_j = jax.random.randint(kj, (rows,), 0, clients.counts[j])
             xi = flat_x[i * n_max + ridx_i]
             xj = flat_x[j * n_max + ridx_j]
-            gi = jax.grad(_binary_loss)(hi, xi, jnp.zeros(batch, jnp.int32))
-            gj = jax.grad(_binary_loss)(hj, xj, jnp.ones(batch, jnp.int32))
+            gi = jax.grad(binary_loss)(hi, xi, jnp.zeros(rows, jnp.int32))
+            gj = jax.grad(binary_loss)(hj, xj, jnp.ones(rows, jnp.int32))
             hi = jax.tree_util.tree_map(lambda a, g: a - lr * g, hi, gi)
             hj = jax.tree_util.tree_map(lambda a, g: a - lr * g, hj, gj)
             # parameter exchange + average every T local iterations
@@ -107,7 +114,7 @@ def pairwise_divergence_values(h0, clients: StackedClients, pair_i, pair_j,
 
         def dev_err(d, lab):
             x = flat_x[d * n_max + row]
-            pred = jnp.argmax(cnn.cnn_forward(hbar, x), axis=-1)
+            pred = jnp.argmax(client.pair_logits(hbar, x), axis=-1)
             valid = row < clients.counts[d]
             wrong = jnp.logical_and(valid, pred != lab)
             return jnp.sum(wrong.astype(jnp.float32)), \
@@ -175,25 +182,25 @@ def chunked_pair_lanes(pi, pj, keys, width: int, call, *,
     return out
 
 
-def _chunked_pair_values(h0, clients: StackedClients, pi, pj, keys, *,
+def _chunked_pair_values(h0, clients: PairInputs, pi, pj, keys, *,
                          tau: int, T: int, batch: int, lr: float,
-                         pair_chunk: int) -> np.ndarray:
+                         pair_chunk: int, client=None) -> np.ndarray:
     """Local (single-host) pair estimation: one vmapped call for small
     batches, fixed-width padded chunks beyond ``pair_chunk``."""
     def call(ci, cj, ck):
         return pairwise_divergence_values(
             h0, clients, jnp.asarray(ci), jnp.asarray(cj), ck,
-            tau=tau, T=T, batch=batch, lr=lr)
+            tau=tau, T=T, batch=batch, lr=lr, client=client)
 
     return chunked_pair_lanes(pi, pj, keys, pair_chunk, call,
                               pad_partial=False)
 
 
-def estimate_divergences(clients: StackedClients, key, *, tau: int = 4,
+def estimate_divergences(clients: PairInputs, key, *, tau: int = 4,
                          T: int = 25, batch: int = 10, lr: float = 0.01,
                          pairs=None, pair_chunk: int = 256,
                          values_fn=None, keys=None,
-                         h0=None) -> np.ndarray:
+                         h0=None, client=None) -> np.ndarray:
     """Algorithm 1: returns the symmetric (N, N) matrix of empirical
     d_H estimates (diagonal 0).
 
@@ -246,7 +253,7 @@ def estimate_divergences(clients: StackedClients, key, *, tau: int = 4,
     if keys is None or h0 is None:
         key, init_key = jax.random.split(key)
         if h0 is None:
-            h0 = cnn.cnn_init(init_key, num_classes=2)
+            h0 = _client(client).pair_init(init_key)
         if keys is None:
             keys = pair_keys(key, len(pi), pair_chunk)
 
@@ -255,17 +262,18 @@ def estimate_divergences(clients: StackedClients, key, *, tau: int = 4,
                                  tau=tau, T=T, batch=batch, lr=lr))
     else:
         d = _chunked_pair_values(h0, clients, pi, pj, keys, tau=tau, T=T,
-                                 batch=batch, lr=lr, pair_chunk=pair_chunk)
+                                 batch=batch, lr=lr, pair_chunk=pair_chunk,
+                                 client=client)
     out = np.zeros((n, n))
     out[pi, pj] = d
     out[pj, pi] = d
     return out
 
 
-def update_divergences(div: np.ndarray, clients: StackedClients, key,
+def update_divergences(div: np.ndarray, clients: PairInputs, key,
                        pairs, *, tau: int = 4, T: int = 25, batch: int = 10,
                        lr: float = 0.01, ema=0.0, values_fn=None,
-                       keys=None, h0=None) -> np.ndarray:
+                       keys=None, h0=None, client=None) -> np.ndarray:
     """Incrementally refresh ``div`` on the given (P, 2) pairs only and
     return the merged copy (Algorithm 1 run just for those links) — the
     pair-incremental path every divergence mutation in the simulator
@@ -287,7 +295,7 @@ def update_divergences(div: np.ndarray, clients: StackedClients, key,
         no longer exists: 0 again (keeping any of it would anchor the
         solver to the pre-drift world)
 
-    ``values_fn``, ``keys`` and ``h0`` are forwarded to
+    ``values_fn``, ``keys``, ``h0`` and ``client`` are forwarded to
     ``estimate_divergences`` (the placement hook and the
     content-addressed-key override; see there for both contracts)."""
     pairs = np.atleast_2d(np.asarray(pairs, np.int32))
@@ -296,7 +304,7 @@ def update_divergences(div: np.ndarray, clients: StackedClients, key,
         return out
     fresh = estimate_divergences(clients, key, tau=tau, T=T, batch=batch,
                                  lr=lr, pairs=pairs, values_fn=values_fn,
-                                 keys=keys, h0=h0)
+                                 keys=keys, h0=h0, client=client)
     pi, pj = pairs[:, 0], pairs[:, 1]        # vectorized symmetric scatter
     w = np.broadcast_to(np.asarray(ema, float), pi.shape)
     out[pi, pj] = w * out[pi, pj] + (1.0 - w) * fresh[pi, pj]

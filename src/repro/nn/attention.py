@@ -17,12 +17,15 @@ NEG_INF = -2.0e9
 
 
 def attention_specs(d_model: int, num_heads: int, num_kv_heads: int,
-                    head_dim: int):
+                    head_dim: int, d_out: Optional[int] = None):
+    """q, k, v read ``d_model`` wide inputs; o writes ``d_out`` (default
+    ``d_model``) wide outputs."""
     return {
         "wq": ParamSpec((d_model, num_heads, head_dim), ("embed", "heads", "qkv")),
         "wk": ParamSpec((d_model, num_kv_heads, head_dim), ("embed", "kv_heads", "qkv")),
         "wv": ParamSpec((d_model, num_kv_heads, head_dim), ("embed", "kv_heads", "qkv")),
-        "wo": ParamSpec((num_heads, head_dim, d_model), ("heads", "qkv", "embed")),
+        "wo": ParamSpec((num_heads, head_dim, d_out or d_model),
+                        ("heads", "qkv", "embed")),
     }
 
 
@@ -36,11 +39,14 @@ def _repeat_kv(k, num_heads):
     return jnp.reshape(k, (b, s, kv * rep, hd))
 
 
-def dot_attention(q, k, v, mask, dtype=jnp.bfloat16):
-    """q: (B,Sq,H,hd); k,v: (B,Sk,H,hd); mask (B,1,Sq,Sk) or (1,1,Sq,Sk)."""
+def dot_attention(q, k, v, mask, dtype=jnp.bfloat16, scale=None):
+    """q: (B,Sq,H,hd); k,v: (B,Sk,H,hd); mask (B,1,Sq,Sk) or (1,1,Sq,Sk).
+    Scores are scaled by ``scale`` (default hd^-1/2)."""
     hd = q.shape[-1]
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) / jnp.sqrt(float(hd))
+                        k.astype(jnp.float32))
+    scores = scores / jnp.sqrt(float(hd)) if scale is None \
+        else scores * scale
     scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(dtype), v.astype(dtype))
@@ -110,7 +116,7 @@ def causal_mask(sq: int, sk: int, window: Optional[int] = None,
 
 def attend(params, x, positions, *, num_heads, num_kv_heads, head_dim,
            rope_theta, causal=True, window=None, ctx: ShardCtx = NO_SHARD,
-           dtype=jnp.bfloat16, cross_kv=None, impl="xla"):
+           dtype=jnp.bfloat16, cross_kv=None, impl="xla", scale=None):
     """Self (or cross) attention over a full sequence (train / prefill).
 
     x: (B, S, D).  cross_kv: optional (k, v) from an encoder
@@ -147,7 +153,8 @@ def attend(params, x, positions, *, num_heads, num_kv_heads, head_dim,
         else:
             mask = causal_mask(s, sk, window=window)
         out = dot_attention(q, _repeat_kv(k, num_heads),
-                            _repeat_kv(v, num_heads), mask, dtype=dtype)
+                            _repeat_kv(v, num_heads), mask, dtype=dtype,
+                            scale=scale)
     out = ctx.constrain(out, "batch", None, "heads", None)
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dtype))
 
@@ -171,7 +178,7 @@ def cache_specs(batch: int, max_len: int, num_kv_heads: int, head_dim: int,
 
 def decode_attend(params, x, cache, pos, *, num_heads, num_kv_heads,
                   head_dim, rope_theta, window=None, ctx: ShardCtx = NO_SHARD,
-                  dtype=jnp.bfloat16, cross_kv=None):
+                  dtype=jnp.bfloat16, cross_kv=None, scale=None):
     """One-token decode.  x: (B, 1, D); pos: (B,) current absolute position.
 
     With ``window`` the cache is a ring buffer of size ``window`` (slot =
@@ -217,6 +224,7 @@ def decode_attend(params, x, cache, pos, *, num_heads, num_kv_heads,
     mask = valid[:, None, None, :]                            # (B,1,1,S)
 
     out = dot_attention(q, _repeat_kv(k, num_heads),
-                        _repeat_kv(v, num_heads), mask, dtype=dtype)
+                        _repeat_kv(v, num_heads), mask, dtype=dtype,
+                        scale=scale)
     out = ctx.constrain(out, "batch", None, "heads", None)
     return jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(dtype)), new_cache

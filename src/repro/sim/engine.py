@@ -41,7 +41,8 @@ from repro.data.digits import DOMAINS, render_images
 from repro.data.partition import (DeviceData, build_network,
                                   interpolate_features, make_device,
                                   reveal_labels)
-from repro.fl.client import (init_client_params, pad_clients,
+from repro.data.lm_stream import build_token_network
+from repro.fl.client import (init_client_params, make_client, pad_clients,
                              stack_clients)
 from repro.fl.transfer import column_normalize
 from repro.sim.executors import get_executor
@@ -61,6 +62,15 @@ class SimConfig:
     setting: str = "M//MM"
     samples_per_device: int = 100
     spares: int = -1             # -1: let the scenario choose
+    #: the client model (repro.fl.client): "cnn", the paper's CNN over
+    #: digit images, or a registered hybrid configuration ("zamba2-7b")
+    #: tagging token sequences of ``seq_len``, cut to
+    #: ``num_hidden_layers`` layers when that is positive; a token client
+    #: reads ``setting`` as its split of token domains ("T1//T2//T3":
+    #: device i draws domain i mod 3)
+    client: str = "cnn"
+    num_hidden_layers: int = 0
+    seq_len: int = 1024
     # execution layer (repro.sim.executors)
     engine: str = "sync"
     # device-pool backend (repro.sim.shard.pool): 0 = single-host
@@ -287,10 +297,29 @@ class SimulationEngine:
         self.scenario = scen_cls(cfg, np.random.default_rng(cfg.seed + 1))
         self.key = jax.random.PRNGKey(cfg.seed)
 
+        self.client = make_client(cfg.client,
+                                  num_hidden_layers=cfg.num_hidden_layers,
+                                  seq_len=cfg.seq_len)
         spares = cfg.spares if cfg.spares >= 0 else scen_cls.wants_spares
-        pool = build_network(cfg.setting, num_devices=cfg.devices,
-                             samples_per_device=cfg.samples_per_device,
-                             seed=cfg.seed)
+        if self.client.tokens_per_sample:
+            if spares:
+                raise ValueError(
+                    f"scenario {cfg.scenario!r} wants {spares} spare "
+                    f"devices; token clients ({cfg.client}) run without "
+                    f"spares")
+            if "//" not in cfg.setting:
+                raise ValueError(
+                    f"token clients take a split setting of token "
+                    f"domains (e.g. T1//T2//T3), got {cfg.setting!r}")
+            pool = build_token_network(
+                cfg.devices, cfg.samples_per_device, cfg.seq_len,
+                vocab_size=self.client.cfg.vocab_size, seed=cfg.seed,
+                num_topics=self.client.num_classes,
+                num_domains=len(cfg.setting.split("//")))
+        else:
+            pool = build_network(cfg.setting, num_devices=cfg.devices,
+                                 samples_per_device=cfg.samples_per_device,
+                                 seed=cfg.seed)
         for k in range(spares):
             ratio = 0.0 if self.rng.random() < 0.5 \
                 else float(self.rng.uniform(0.3, 0.9))
@@ -302,10 +331,14 @@ class SimulationEngine:
         active[:cfg.devices] = True
 
         k_init, self.key = jax.random.split(self.key)
+        #: the client's shared part, and the initial per-device leaves
+        #: every device starts from (Algorithm 1's features use them)
+        self.frozen = self.client.init_frozen(k_init)
+        self._p0 = self.client.init(k_init)
         self.state = NetworkState(
             round=0, pool=pool, active=active,
             clients=stack_clients(pool),
-            params=init_client_params(p, k_init),
+            params=init_client_params(p, k_init, client=self.client),
             eps_hat=np.ones(p), own_acc=np.zeros(p),
             div_hat=np.zeros((p, p)), div_known=np.eye(p, dtype=bool),
             div_dirty=np.zeros((p, p), bool),
@@ -334,6 +367,9 @@ class SimulationEngine:
         self.trace = TraceRecorder(cfg)
         self.pool = make_pool(self)
         self.state.clients = self.pool.place_clients(self.state.clients)
+        self.frozen = self.pool.place_frozen(self.frozen)
+        #: Algorithm 1's rows of a featurized client (``featurize``)
+        self._features = None
         self.executor = get_executor(cfg.engine)(self)
         self.executor.setup()
         self.scenario.setup(self)
@@ -394,6 +430,10 @@ class SimulationEngine:
         """
         st = self.state
         j = int(device)
+        if self.client.tokens_per_sample:
+            raise NotImplementedError(
+                f"feature drift blends images; the {self.cfg.client} "
+                f"client's token data has no drift")
         if j not in self._drift_base:
             base = st.pool[j]
             if domain is None:
@@ -410,7 +450,7 @@ class SimulationEngine:
         # base, but labels may have been revealed since it was cached
         # (label-arrival composing with feature drift), so the device's
         # CURRENT label state is carried, never the cached one
-        st.pool[j] = DeviceData(blended.images, cur.labels,
+        st.pool[j] = DeviceData(blended.x, cur.labels,
                                 cur.labeled_mask, cur.domain_ids,
                                 cur.true_labels)
         st.mark_pairs_dirty(j)
@@ -439,6 +479,32 @@ class SimulationEngine:
         nbytes = sum(leaf.nbytes
                      for leaf in jax.tree_util.tree_leaves(stack))
         return nbytes, len(rows)
+
+    def pair_inputs(self, clients):
+        """The rows Algorithm 1 classifies for the stack ``clients``: the
+        client's own (the CNN's images), or the features ``featurize``
+        keeps for a featurized client."""
+        if not self.client.featurized:
+            return self.client.pair_inputs(self.frozen, self._p0, clients)
+        if self._features is None:
+            raise RuntimeError("pair_inputs before featurize()")
+        return self._features
+
+    def featurize(self) -> int:
+        """Compute a featurized client's Algorithm-1 rows, once: one
+        forward of every device's samples under the shared initial
+        leaves.  A token client's samples never change (it refuses
+        feature drift and spare devices; a label reveal leaves them as
+        they are), so this version of the rows is the only one.  Returns
+        the devices featurized."""
+        if not self.client.featurized or self._features is not None:
+            return 0
+        span = self.trace.start("features")
+        self._features = jax.jit(self.client.pair_inputs)(
+            self.frozen, self._p0, self.state.clients)
+        self.trace.stop(span, block=self._features,
+                        n_devices=self.state.pool_size)
+        return self.state.pool_size
 
     def _reseed_device(self, j: int):
         """Churn-robust transfer: a (re)joining device adopts the

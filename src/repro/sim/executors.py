@@ -142,7 +142,8 @@ class Executor:
                            nbytes=restack_bytes)
         return t0, events, dict(restack_bytes=int(restack_bytes),
                                 restack_rows=int(restack_rows),
-                                n_rendered=int(n_rendered))
+                                n_rendered=int(n_rendered),
+                                n_featurized=eng.featurize())
 
     def _gate(self, a: np.ndarray, t: int, drift: float,
               patience: int = 0):
@@ -257,11 +258,9 @@ class Executor:
         (fixed so refresh measurements are content-addressed; cached —
         it is the same tree every tick)."""
         if not hasattr(self, "_refresh_h0_cache"):
-            from repro.fl import cnn
-            self._refresh_h0_cache = cnn.cnn_init(
+            self._refresh_h0_cache = self.engine.client.pair_init(
                 jax.random.fold_in(
-                    jax.random.PRNGKey(self.engine.cfg.seed), 2 ** 21),
-                num_classes=2)
+                    jax.random.PRNGKey(self.engine.cfg.seed), 2 ** 21))
         return self._refresh_h0_cache
 
     def _run_solve(self, a: np.ndarray, t: int):
@@ -275,6 +274,19 @@ class Executor:
         eng.trace.stop(span, n_devices=len(a))
         # the row's solver_wall_s keeps the solver's own measurement
         return warm, res.outer_iters, res.solve_time_s
+
+    def _token_counts(self, a: np.ndarray) -> dict:
+        """The sync round's trained devices, and the token positions of
+        its training (``n_trained * train_iters * batch`` samples) and of
+        its three measurement sweeps over the active devices' samples (0
+        for a client whose samples are not token sequences)."""
+        st, cfg = self.engine.state, self.engine.cfg
+        tok = self.engine.client.tokens_per_sample
+        n_trained = int(np.sum(st.labeled_devices[a]))
+        samples = sum(st.pool[j].n for j in a)
+        return dict(n_trained=n_trained,
+                    train_tokens=n_trained * cfg.train_iters * cfg.batch
+                    * tok, eval_tokens=3 * samples * tok)
 
     def _link_churn(self) -> float:
         """Jaccard distance of the active-link set vs. the previous
@@ -393,7 +405,7 @@ class SyncExecutor(Executor):
                 st.alpha, thresh=cfg.link_thresh),
             churn=churn, solve_age=solve_age, reason=reason,
             n_dirty_pairs=n_dirty, n_reestimated=n_reest, **counts,
-            n_trained=int(np.sum(st.labeled_devices[a])))
+            **self._token_counts(a))
         if cfg.verbose:
             print(f"[sim] round {t}: active={len(a)} "
                   f"src={record.n_sources} tgt={record.n_targets} "

@@ -72,6 +72,16 @@ scenarios read exactly as before):
   restack_rows     int    rows of the client stack written this tick
                           (the pool size on a full restack)
 
+Client-model fields (0 for the CNN):
+  n_featurized     int    devices whose Algorithm-1 features a
+                          featurized client computed this tick (every
+                          device in the first tick, 0 after)
+  train_tokens     int    token positions trained this sync round
+                          (n_trained * train_iters * batch * seq_len)
+  eval_tokens      int    token positions of the active devices'
+                          samples through the round's three measurement
+                          sweeps
+
 Fault-tolerance fields (added with the checkpoint/resume + fault
 injection layer; all 0 on fault-free, never-resumed runs):
   n_faults         int    faults injected this tick (device crashes,
@@ -90,6 +100,7 @@ nondeterministic):
   scenario_wall_s  float  wall seconds in the scenario's mutation
   restack_wall_s   float  wall seconds writing changed data into the
                           client stack
+  features_wall_s  float  wall seconds computing Algorithm-1 features
   train_wall_s     float  wall seconds in the pool's training phase
   div_wall_s       float  wall seconds in Algorithm-1 estimation
                           (bootstrap + gossip + budgeted refresh)
@@ -124,6 +135,7 @@ from typing import IO, List, Optional
 # field-for-field except for the counter that says it was resumed)
 NONDETERMINISTIC_FIELDS = ("wall_time_s", "solver_wall_s",
                            "scenario_wall_s", "restack_wall_s",
+                           "features_wall_s",
                            "train_wall_s", "div_wall_s",
                            "refresh_select_wall_s", "transfer_wall_s",
                            "eval_wall_s", "ckpt_wall_s", "n_compiled",
@@ -171,6 +183,12 @@ class RoundRecord:
     n_rendered: int = 0
     restack_bytes: int = 0
     restack_rows: int = 0
+    # client-model fields: devices whose Algorithm-1 features were
+    # computed, and token positions trained and measured (0 for a client
+    # whose samples are not token sequences; sync rounds only)
+    n_featurized: int = 0
+    train_tokens: int = 0
+    eval_tokens: int = 0
     # fault-tolerance fields (0 when no faults are injected / no resume)
     n_faults: int = 0
     n_recovered: int = 0
@@ -181,6 +199,7 @@ class RoundRecord:
     # round's record is already emitted)
     scenario_wall_s: float = 0.0
     restack_wall_s: float = 0.0
+    features_wall_s: float = 0.0
     train_wall_s: float = 0.0
     div_wall_s: float = 0.0
     refresh_select_wall_s: float = 0.0

@@ -50,32 +50,37 @@ def _smap(body, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
-def build_train_step(mesh, *, iters: int, batch: int, lr: float):
-    """(params, clients, keys, active, train_mask) -> (params', eps, acc),
-    every argument padded to a multiple of the shard count and
+def build_train_step(mesh, *, iters: int, batch: int, lr: float,
+                     client=None):
+    """(frozen, params, clients, keys, active, train_mask) -> (params',
+    eps, acc): ``frozen`` (the client's shared part) replicated, every
+    other argument padded to a multiple of the shard count and
     device-sharded; per-device keys come from the caller (the full
     pool's ``split``, exactly the single-host stream)."""
     spec = P(DEVICE_AXIS)
 
-    def body(p, c, k, a, m):
-        return network_step_core(p, c, k, a, m,
-                                 iters=iters, batch=batch, lr=lr)
+    def body(f, p, c, k, a, m):
+        return network_step_core(p, c, k, a, m, iters=iters, batch=batch,
+                                 lr=lr, client=client, frozen=f)
 
-    return jax.jit(_smap(body, mesh, (spec,) * 5, (spec,) * 3))
+    return jax.jit(_smap(body, mesh, (P(),) + (spec,) * 5, (spec,) * 3))
 
 
-def build_pair_values(mesh, *, tau: int, T: int, batch: int, lr: float):
-    """(h0, clients, pi, pj, keys) -> (npairs,) d_H values; the PAIR axis
-    is device-sharded (padded by the caller), clients are device-sharded
-    and all-gathered inside — the cross-shard gather that lets any shard
-    estimate any pair."""
+def build_pair_values(mesh, *, tau: int, T: int, batch: int, lr: float,
+                      client=None):
+    """(h0, rows, pi, pj, keys) -> (npairs,) d_H values; the PAIR axis
+    is device-sharded (padded by the caller), the rows Algorithm 1
+    classifies (``PairInputs``) are device-sharded and all-gathered
+    inside — the cross-shard gather that lets any shard estimate any
+    pair."""
     spec = P(DEVICE_AXIS)
 
     def body(h0, c, pi, pj, keys):
         full = jax.tree_util.tree_map(
             lambda a: jax.lax.all_gather(a, DEVICE_AXIS, tiled=True), c)
         return pairwise_divergence_values(h0, full, pi, pj, keys,
-                                          tau=tau, T=T, batch=batch, lr=lr)
+                                          tau=tau, T=T, batch=batch, lr=lr,
+                                          client=client)
 
     return jax.jit(_smap(body, mesh, (P(), spec, spec, spec, spec), spec))
 
@@ -106,8 +111,10 @@ def build_transfer(mesh):
                          spec))
 
 
-def build_accuracies(mesh):
-    """(params, clients) -> (P',) ground-truth accuracies, per-shard."""
+def build_accuracies(mesh, client=None):
+    """(frozen, params, clients) -> (P',) ground-truth accuracies,
+    per-shard; ``frozen`` replicated."""
     spec = P(DEVICE_AXIS)
-    return jax.jit(_smap(lambda p, c: true_accuracies(p, c), mesh,
-                         (spec, spec), spec))
+    return jax.jit(_smap(
+        lambda f, p, c: true_accuracies(p, c, client=client, frozen=f),
+        mesh, (P(), spec, spec), spec))

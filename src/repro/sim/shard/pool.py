@@ -146,9 +146,10 @@ class DevicePool:
         cfg = self.engine.cfg
         span = self.engine.trace.start("divergence")
         out = _update_divergences(
-            div, clients, key, pairs, tau=cfg.div_tau, T=cfg.div_T,
-            batch=cfg.batch, lr=cfg.lr, ema=ema,
-            values_fn=self._values_fn(), keys=keys, h0=h0)
+            div, self.engine.pair_inputs(clients), key, pairs,
+            tau=cfg.div_tau, T=cfg.div_T, batch=cfg.batch, lr=cfg.lr,
+            ema=ema, values_fn=self._values_fn(), keys=keys, h0=h0,
+            client=self.engine.client)
         self.engine.trace.stop(span, block=out,
                                n_devices=clients.n_devices,
                                n_pairs=len(pairs))
@@ -168,9 +169,10 @@ class DevicePool:
         cfg = self.engine.cfg
         span = self.engine.trace.start("divergence")
         out = _update_divergences(
-            div, clients, key, pairs, tau=cfg.div_tau, T=cfg.div_T,
-            batch=cfg.batch, lr=cfg.lr, ema=ema,
-            values_fn=self._targeted_values_fn(), keys=keys, h0=h0)
+            div, self.engine.pair_inputs(clients), key, pairs,
+            tau=cfg.div_tau, T=cfg.div_T, batch=cfg.batch, lr=cfg.lr,
+            ema=ema, values_fn=self._targeted_values_fn(), keys=keys,
+            h0=h0, client=self.engine.client)
         self.engine.trace.stop(span, block=out,
                                n_devices=clients.n_devices,
                                n_pairs=len(pairs))
@@ -194,6 +196,14 @@ class DevicePool:
         every freshly stacked ``StackedClients`` through here.  The base
         keeps it on the default device."""
         return clients
+
+    def place_frozen(self, frozen):
+        """Where the client model's frozen part lives: placed once, every
+        phase reads it un-batched.  The base keeps it where it is."""
+        return frozen
+
+    def _model(self) -> dict:
+        return dict(client=self.engine.client, frozen=self.engine.frozen)
 
     def write_client_rows(self, clients, rows, block):
         """Write the changed devices into the placed stack: ``block``
@@ -312,7 +322,7 @@ class LocalPool(DevicePool):
         mask = None if train_mask is None else jnp.asarray(train_mask)
         return network_step(params, clients, key, jnp.asarray(active),
                             mask, iters=cfg.train_iters, batch=cfg.batch,
-                            lr=cfg.lr)
+                            lr=cfg.lr, **self._model())
 
     def _train_async(self, params, clients, key, active, elig,
                      eps_prev, acc_prev):
@@ -346,7 +356,8 @@ class LocalPool(DevicePool):
             jax.tree_util.tree_map(sub, params),
             jax.tree_util.tree_map(sub, clients),
             keys[gj], jnp.asarray(active)[gj],
-            iters=cfg.train_iters, batch=cfg.batch, lr=cfg.lr)
+            iters=cfg.train_iters, batch=cfg.batch, lr=cfg.lr,
+            **self._model())
         k = len(g)
         gi = jnp.asarray(g)
         params = jax.tree_util.tree_map(
@@ -361,7 +372,7 @@ class LocalPool(DevicePool):
                               jnp.asarray(psi))
 
     def _accuracies(self, params, clients):
-        return mixed_accuracies(params, clients)
+        return mixed_accuracies(params, clients, **self._model())
 
     def _targeted_values_fn(self):
         """Single-host row targeting: one bucketed row gather for the
@@ -379,7 +390,8 @@ class LocalPool(DevicePool):
                 return pairwise_divergence_values(
                     h0, sub, jnp.asarray(ci, jnp.int32),
                     jnp.asarray(cj, jnp.int32), ck,
-                    tau=tau, T=T, batch=batch, lr=lr)
+                    tau=tau, T=T, batch=batch, lr=lr,
+                    client=self.engine.client)
 
             return chunked_pair_lanes(
                 ri, rj, keys, self._width(
@@ -398,13 +410,15 @@ class ShardedPool(DevicePool):
         self.n_shards = self.mesh.shape[DEVICE_AXIS]
         self.name = f"sharded-{self.n_shards}"
         cfg = engine.cfg
+        client = engine.client
         self._train_fn = ops.build_train_step(
-            self.mesh, iters=cfg.train_iters, batch=cfg.batch, lr=cfg.lr)
+            self.mesh, iters=cfg.train_iters, batch=cfg.batch, lr=cfg.lr,
+            client=client)
         self._pair_fn = ops.build_pair_values(
             self.mesh, tau=cfg.div_tau, T=cfg.div_T, batch=cfg.batch,
-            lr=cfg.lr)
+            lr=cfg.lr, client=client)
         self._transfer_fn = ops.build_transfer(self.mesh)
-        self._acc_fn = ops.build_accuracies(self.mesh)
+        self._acc_fn = ops.build_accuracies(self.mesh, client)
 
     def place_clients(self, clients):
         """Shard the client stack over the pool mesh once, so each chip
@@ -415,6 +429,11 @@ class ShardedPool(DevicePool):
             return clients
         return jax.device_put(clients, NamedSharding(
             self.mesh, PartitionSpec(DEVICE_AXIS)))
+
+    def place_frozen(self, frozen):
+        """Replicated over the pool mesh, once."""
+        return jax.device_put(frozen, NamedSharding(self.mesh,
+                                                    PartitionSpec()))
 
     # ------------------------------------------------------ pool padding
     def _pad(self, n: int) -> int:
@@ -467,6 +486,7 @@ class ShardedPool(DevicePool):
         mask = np.ones(n, bool) if train_mask is None \
             else np.asarray(train_mask, bool)
         out, eps, acc = self._train_fn(
+            self.engine.frozen,
             self._pad_tree(params, pad), self._pad_tree(clients, pad),
             self._pad_tree(keys, pad),
             jnp.asarray(self._pad_mask(active, pad)),
@@ -547,5 +567,5 @@ class ShardedPool(DevicePool):
     def _accuracies(self, params, clients):
         n = clients.n_devices
         pad = self._pad(n)
-        return self._acc_fn(self._pad_tree(params, pad),
+        return self._acc_fn(self.engine.frozen, self._pad_tree(params, pad),
                             self._pad_tree(clients, pad))[:n]

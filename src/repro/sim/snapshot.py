@@ -74,8 +74,8 @@ RESUME_EXEMPT_CFG = frozenset({
 
 def _key(*names) -> str:
     """The jax keystr of a nested-dict path — how save_checkpoint names
-    archive members (``_key('pool', '00003', 'images')`` ->
-    ``"['pool']['00003']['images']"``)."""
+    archive members (``_key('pool', '00003', 'x')`` ->
+    ``"['pool']['00003']['x']"``)."""
     return "".join(f"[{n!r}]" for n in names)
 
 
@@ -84,7 +84,7 @@ def _slot(j: int) -> str:
 
 
 def _device_arrays(dev: DeviceData) -> Dict[str, np.ndarray]:
-    return {"images": np.asarray(dev.images),
+    return {"x": np.asarray(dev.x),
             "labels": np.asarray(dev.labels),
             "labeled_mask": np.asarray(dev.labeled_mask),
             "domain_ids": np.asarray(dev.domain_ids),
@@ -93,7 +93,7 @@ def _device_arrays(dev: DeviceData) -> Dict[str, np.ndarray]:
 
 def _device_from(arrs: Dict[str, np.ndarray], *prefix) -> DeviceData:
     g = lambda f: arrs[_key(*prefix, f)]                  # noqa: E731
-    return DeviceData(images=g("images"), labels=g("labels"),
+    return DeviceData(x=g("x"), labels=g("labels"),
                       labeled_mask=g("labeled_mask"),
                       domain_ids=g("domain_ids"),
                       true_labels=g("true_labels"))

@@ -15,6 +15,9 @@ The phases of a tick are top-level and disjoint, in the order they run:
   ``restack``         writing changed devices' rows into the placed
                       client stack (or re-stacking it), after data
                       changed
+  ``features``        a featurized client's Algorithm-1 rows: one
+                      forward of every device's samples, in the first
+                      tick
   ``train``           local training through the pool
   ``divergence``      Algorithm-1 pair estimation through the pool
                       (bootstrap, gossip meetings, budgeted refresh)
@@ -66,6 +69,7 @@ from typing import IO, List, Optional
 WALL_FIELDS = {
     "scenario": "scenario_wall_s",
     "restack": "restack_wall_s",
+    "features": "features_wall_s",
     "train": "train_wall_s",
     "divergence": "div_wall_s",
     "refresh_select": "refresh_select_wall_s",
@@ -74,8 +78,9 @@ WALL_FIELDS = {
     "checkpoint": "ckpt_wall_s",
 }
 
-PHASES = ("scenario", "restack", "train", "divergence", "refresh_select",
-          "solve", "transfer", "eval", "log", "checkpoint")
+PHASES = ("scenario", "restack", "features", "train", "divergence",
+          "refresh_select", "solve", "transfer", "eval", "log",
+          "checkpoint")
 
 #: prefix of the profiler span of every phase
 SPAN_PREFIX = "sim."

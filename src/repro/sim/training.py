@@ -25,16 +25,18 @@ from repro.fl.client import (StackedClients, empirical_errors,
 
 def network_step_core(params, clients: StackedClients, keys, active,
                       train_mask=None, *, iters: int, batch: int,
-                      lr: float):
+                      lr: float, client=None, frozen=None):
     """The traceable body shared by every entry point: ``network_step``
     (full pool, one host), ``subset_network_step`` (compact gathered
     lanes), and the mesh-sharded pool (per-shard slices under shard_map).
     ``keys``: per-device PRNG keys, (N, key_dim) — every lane is
     independent, so callers may gather/shard the device axis freely
-    without changing any lane's result."""
+    without changing any lane's result.  ``client`` is the model
+    (default the paper's CNN), ``frozen`` its shared part."""
+    model = dict(client=client, frozen=frozen)
     with jax.named_scope("train_scan"):
         trained = train_sources(params, clients, keys,
-                                iters=iters, batch=batch, lr=lr)
+                                iters=iters, batch=batch, lr=lr, **model)
     update = jnp.logical_and(jnp.any(clients.labeled, axis=1),
                              jnp.asarray(active))           # (N,)
     if train_mask is not None:
@@ -45,14 +47,16 @@ def network_step_core(params, clients: StackedClients, keys, active,
         return jnp.where(m, new, old)
 
     params = jax.tree_util.tree_map(keep, trained, params)
-    eps = empirical_errors(params, clients)
-    acc = true_accuracies(params, clients)
+    eps = empirical_errors(params, clients, **model)
+    acc = true_accuracies(params, clients, **model)
     return params, eps, acc
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "batch", "lr"))
+@functools.partial(jax.jit, static_argnames=("iters", "batch", "lr",
+                                             "client"))
 def network_step(params, clients: StackedClients, key, active,
-                 train_mask=None, *, iters: int, batch: int, lr: float):
+                 train_mask=None, *, iters: int, batch: int, lr: float,
+                 client=None, frozen=None):
     """One simulator round of local training for every device at once.
 
     ``active``: (N,) bool — devices currently in the network.  Departed
@@ -77,12 +81,15 @@ def network_step(params, clients: StackedClients, key, active,
     """
     keys = jax.random.split(key, clients.n_devices)
     return network_step_core(params, clients, keys, active, train_mask,
-                             iters=iters, batch=batch, lr=lr)
+                             iters=iters, batch=batch, lr=lr,
+                             client=client, frozen=frozen)
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "batch", "lr"))
+@functools.partial(jax.jit, static_argnames=("iters", "batch", "lr",
+                                             "client"))
 def subset_network_step(params, clients: StackedClients, keys, active, *,
-                        iters: int, batch: int, lr: float):
+                        iters: int, batch: int, lr: float, client=None,
+                        frozen=None):
     """Compact-lane variant for the async subset-gather path: the caller
     gathers ONLY the clock-eligible lanes (params/clients rows and their
     per-device keys from the full pool's ``split``), so no masked no-op
@@ -90,10 +97,12 @@ def subset_network_step(params, clients: StackedClients, keys, active, *,
     to the masked full-pool step — lanes are independent and keep their
     full-pool PRNG keys — which the parity test pins."""
     return network_step_core(params, clients, keys, active, None,
-                             iters=iters, batch=batch, lr=lr)
+                             iters=iters, batch=batch, lr=lr,
+                             client=client, frozen=frozen)
 
 
-@jax.jit
-def mixed_accuracies(params, clients: StackedClients):
+@functools.partial(jax.jit, static_argnames=("client",))
+def mixed_accuracies(params, clients: StackedClients, *, client=None,
+                     frozen=None):
     """Ground-truth accuracy of (post-transfer) stacked params."""
-    return true_accuracies(params, clients)
+    return true_accuracies(params, clients, client=client, frozen=frozen)

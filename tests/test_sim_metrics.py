@@ -33,7 +33,7 @@ def test_nondeterministic_fields_exist_on_record():
     assert set(NONDETERMINISTIC_FIELDS) <= names
     assert set(NONDETERMINISTIC_FIELDS) == {
         "wall_time_s", "solver_wall_s", "scenario_wall_s",
-        "restack_wall_s", "train_wall_s", "div_wall_s",
+        "restack_wall_s", "features_wall_s", "train_wall_s", "div_wall_s",
         "refresh_select_wall_s", "transfer_wall_s", "eval_wall_s",
         "ckpt_wall_s", "n_compiled", "resume_count"}
 
